@@ -18,12 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 RESULTS_DIR = os.environ.get(
     "BENCH_RESULTS", os.path.join(os.path.dirname(__file__), "results"))
 
 
 def get_mesh(data: int = 4, model: int = 2) -> Mesh:
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model))
 
 
 def timeit(fn: Callable, *args, warmup: int = 2, reps: int = 10,

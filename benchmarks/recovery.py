@@ -137,7 +137,7 @@ def run(quick: bool = False) -> dict:
 
     # generalized Reed-Solomon r-sweep: e = r losses at every stack
     # height on a pure 8-rank zone (r <= 4 needs G - 1 >= 4 survivable)
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh8 = common.get_mesh(8, 1)
     rs_rows = []
     rs_size = 256 * 1024
     for r in (1, 2, 3, 4):

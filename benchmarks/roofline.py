@@ -25,7 +25,8 @@ compared on
     the gate compares (the streamed path is strictly higher: it never
     re-reads the dirty delta, whatever the redundancy);
   * interleaved wall time -> achieved useful bytes/s as a fraction of
-    the `launch.hlo_analysis.HBM_BW` peak (recorded for EXPERIMENTS.md
+    the device's `launch.hlo_analysis.PEAKS` HBM bandwidth, None on a
+    device with no published peaks such as the CPU (EXPERIMENTS.md
     §Roofline; wall cells gate pathology-only, per the standing rule —
     at the 1 MB point the identical GF(2^32) clmul work dominates both
     paths, so wall margins sit inside ambient noise on a shared box).
@@ -86,7 +87,10 @@ def commit_sweep_rows(quick: bool = False) -> list:
     from repro.core import checksum as cksum
     from repro.core import gf
     from repro.kernels import ops
-    from repro.launch.hlo_analysis import HBM_BW
+    from repro.launch.hlo_analysis import PEAKS
+
+    kind = jax.devices()[0].device_kind
+    hbm_bw = PEAKS[kind].hbm_bw if kind in PEAKS else None
 
     r, bw = SWEEP_R, SWEEP_BLOCK_WORDS
     reps = 30 if quick else 60
@@ -159,7 +163,8 @@ def commit_sweep_rows(quick: bool = False) -> list:
                 "useful_MB": round(useful / 2**20, 2),
                 "useful_frac": round(useful / xla[name], 4),
                 "achieved_GBps": round(achieved / 1e9, 2),
-                "frac_of_peak": round(achieved / HBM_BW, 5),
+                "frac_of_peak": (round(achieved / hbm_bw, 5) if hbm_bw
+                                 else None),
             })
     return rows
 
@@ -168,7 +173,7 @@ def run(quick: bool = False, path: str | None = None) -> dict:
     sweep = commit_sweep_rows(quick=quick)
     common.print_table(
         "commit-sweep roofline (streamed vs flat; interleaved reps; "
-        "frac_of_peak = useful bytes/s over HBM_BW)",
+        "frac_of_peak = useful bytes/s over the device's HBM peak)",
         sweep, ["size_B", "path", "r", "wall_us", "xla_MB", "useful_MB",
                 "useful_frac", "achieved_GBps", "frac_of_peak"])
     recs = load_records(path)

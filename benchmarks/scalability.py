@@ -28,7 +28,7 @@ def concurrent_commits(quick: bool) -> list:
     rows = []
     sizes = [4096, 64 * 1024] if quick else [4096, 64 * 1024, 1024 * 1024]
     for g in (2, 4, 8):
-        mesh = jax.make_mesh((g, 1), ("data", "model"))
+        mesh = common.get_mesh(g, 1)
         for size in sizes:
             state, specs = common.state_of_bytes(size * g, mesh)
             pool = Pool.open(state, specs, mesh=mesh,

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ProtectConfig, TrainConfig
+from repro.launch.mesh import make_mesh
 from repro.runtime.trainer import Trainer
 
 
@@ -52,8 +53,8 @@ def main():
     args = ap.parse_args()
     n = 4 if args.smoke else 10
 
-    mesh_full = jax.make_mesh((4, 2), ("data", "model"))
-    mesh_small = jax.make_mesh((2, 2), ("data", "model"))
+    mesh_full = make_mesh((4, 2))
+    mesh_small = make_mesh((2, 2))
 
     t = make_trainer(mesh_full)
     t.initialize()

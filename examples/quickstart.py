@@ -30,10 +30,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import Fault, Pool, ProtectConfig
+from repro.launch.mesh import make_mesh
 from repro.runtime import failure
 
 # 1. a sharded state pytree: FSDP weights, TP weights, a replicated scalar
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2))
 specs = {"w_fsdp": P("data", "model"), "w_tp": P(None, "model"),
          "scale": P()}
 state = {

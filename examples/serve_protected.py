@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ProtectConfig
+from repro.launch.mesh import make_mesh
 from repro.runtime import failure
 from repro.runtime.server import Server
 
@@ -33,7 +34,7 @@ def main():
     if args.smoke:
         args.tokens, args.batch = 16, 4
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     cfg = ModelConfig(
         name="srv-demo", family="dense", n_layers=4, d_model=128, n_heads=8,
         n_kv=2, d_ff=256, vocab=1024, param_dtype="float32",

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ProtectConfig, TrainConfig
+from repro.launch.mesh import make_mesh
 from repro.runtime import failure
 from repro.runtime.trainer import Trainer
 
@@ -63,7 +64,7 @@ def main():
     if args.ckpt_dir is None:
         import tempfile
         args.ckpt_dir = tempfile.mkdtemp(prefix="pangolin_ckpt_")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     cfg = build_cfg(args.d_model)
     trainer = Trainer(
         cfg, TrainConfig(learning_rate=1e-3, warmup_steps=20,

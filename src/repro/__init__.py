@@ -20,8 +20,6 @@ engine layer; everything above them should go through `Pool`.
 
 __version__ = "0.1.0"
 
-from repro import compat as _compat  # noqa: E402,F401  (jax API shims)
-
 __all__ = ["Pool", "Fault", "Transaction", "ProtectConfig", "Mode",
            "Protector", "DeferredProtector", "ProtectedState",
            "MetricsRegistry", "Tracer", "HealthReport"]

@@ -22,13 +22,14 @@ from repro.chaos.runner import ScenarioRunner
 from repro.chaos.schedule import ChaosEvent, FaultSchedule
 from repro.chaos.workload import PoolWorkload
 from repro.configs.base import ProtectConfig
+from repro.launch.mesh import make_mesh
 from repro.obs import Tracer, validate_events
 
 E = ChaosEvent.make
 
 
 def _mesh(shape=(4, 2)):
-    return jax.make_mesh(tuple(shape), ("data", "model"))
+    return make_mesh(shape)
 
 
 def _cfg(**kw) -> ProtectConfig:

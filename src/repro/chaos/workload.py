@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ProtectConfig
+from repro.launch.mesh import make_mesh
 from repro.pool import Pool
 
 # the traffic recurrence: w <- w * GAIN + (step % PERIOD) * STEP_BIAS.
@@ -112,7 +113,7 @@ class PoolWorkload:
 
     def rescale(self, shape) -> None:
         """Elastic resize under traffic: (data, model) mesh shape."""
-        new_mesh = jax.make_mesh(tuple(shape), ("data", "model"))
+        new_mesh = make_mesh(shape)
         self.pool = self.pool.rescale(new_mesh)
         self.mesh = new_mesh
 

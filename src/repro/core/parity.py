@@ -168,14 +168,14 @@ def patch_syndrome_delta(synd: jax.Array, sdelta_pages: jax.Array,
 
 
 def verify_syndromes(row: jax.Array, synd: jax.Array,
-                     axis_name: str) -> jax.Array:
+                     axis_name: str, *, chunks: int = 1) -> jax.Array:
     """Zone invariant per syndrome: returns (r,) bool, zone-agreed.
 
     Entry k is True iff XOR_i g^(k·i)·row_i equals the stored S_k on
     every rank (entry 0 is the classic parity invariant).
     """
     r = synd.shape[0]
-    fresh = coll.syndrome_reduce_scatter(row, r, axis_name)
+    fresh = coll.syndrome_reduce_scatter(row, r, axis_name, chunks=chunks)
     ok_local = jnp.all(fresh == synd, axis=-1)               # (r,)
     return lax.pmin(ok_local.astype(jnp.int32), axis_name) > 0
 

@@ -42,10 +42,8 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core import checksum as ck
 from repro.core import gf
@@ -309,7 +307,8 @@ class Protector:
             outs = {}
             if mode.has_parity:
                 outs["synd"] = self._pack(
-                    parity_mod.build_syndromes(row, r, ax))
+                    parity_mod.build_syndromes(row, r, ax,
+                                               chunks=self.coll_chunks()))
             if mode.has_cksums:
                 cks = ck.block_checksums(row, lo.block_words)
                 outs["cksums"] = self._pack(cks)
@@ -598,7 +597,7 @@ class Protector:
             if mode.has_parity:
                 # every syndrome invariant from one overlapped collective
                 out["synd_ok"] = parity_mod.verify_syndromes(
-                    row, self._unpack(synd), ax)
+                    row, self._unpack(synd), ax, chunks=self.coll_chunks())
             if mode.has_parity or mode.has_cksums:
                 same = jnp.all(row == self._unpack(row_cache))
                 out["row_cache_ok"] = (

@@ -30,31 +30,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import largest_divisor_tile
+from repro.kernels.tiling import row_tile
 
 U32 = jnp.uint32
+I32 = jnp.int32
 TILE_BLOCKS = 8
 # Streamed variants: pages per double-buffered VMEM chunk.  Each operand
 # stages 2 x chunk x block_words words, so the default 8-page chunk costs
 # 64 KB of VMEM per u32 operand at bw=1024 — small enough that the 3-operand
-# accumulate sweep still fits comfortably alongside the output tiles.
+# accumulate sweep still fits comfortably alongside the output tiles.  A
+# chunk is a whole number of 8-row sublane tiles (or the whole row).
 STREAM_CHUNK_BLOCKS = 8
 
 
-def _pick_tb(n: int) -> int:
-    """Largest tile height <= TILE_BLOCKS that divides the block count."""
-    return largest_divisor_tile(n, TILE_BLOCKS)
+def _i32(x):
+    return jax.lax.bitcast_convert_type(x, I32)
+
+
+def _u32(x):
+    return jax.lax.bitcast_convert_type(x, U32)
+
+
+def _fletcher_ab(x):
+    """Per-block Fletcher (A, B) terms of a (tb, bw) u32 tile, as (tb, 1) i32.
+
+    Mosaic has no unsigned reductions, so the sums run in int32 on the
+    bitcast words: two's-complement add and multiply give the same bits
+    mod 2^32 as the u32 arithmetic `kernels/ref.py` specifies.
+    """
+    bw = x.shape[-1]
+    w = I32(bw) - jax.lax.broadcasted_iota(I32, (1, bw), 1)
+    xi = _i32(x)
+    return (jnp.sum(xi, axis=-1, keepdims=True),
+            jnp.sum(xi * w, axis=-1, keepdims=True))
+
+
+def _fletcher_terms(x):
+    """(tb, bw) u32 tile -> (tb, 2) u32 per-block Fletcher terms."""
+    return _u32(jnp.concatenate(_fletcher_ab(x), axis=-1))
+
+
+def _rows(tb, width):
+    """Block of `tb` rows of an (n, width) operand, one per grid step."""
+    return pl.BlockSpec((tb, width), lambda i: (i, 0))
 
 
 def _fused_kernel(old_ref, new_ref, delta_ref, ck_ref):
     old = old_ref[...]
     new = new_ref[...]
     delta_ref[...] = old ^ new
-    bw = new.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
-    a = jnp.sum(new, axis=-1, dtype=U32)
-    b = jnp.sum(new * w, axis=-1, dtype=U32)
-    ck_ref[...] = jnp.stack([a, b], axis=-1)
+    ck_ref[...] = _fletcher_terms(new)
 
 
 def _fused_verify_kernel(old_ref, new_ref, stored_ref, delta_ref, ck_ref,
@@ -62,35 +87,36 @@ def _fused_verify_kernel(old_ref, new_ref, stored_ref, delta_ref, ck_ref,
     old = old_ref[...]
     new = new_ref[...]
     delta_ref[...] = old ^ new
-    bw = new.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
-    # old tile is in VMEM for the delta anyway: its Fletcher terms are free
-    a_old = jnp.sum(old, axis=-1, dtype=U32)
-    b_old = jnp.sum(old * w, axis=-1, dtype=U32)
+    # old tile is in VMEM for the delta anyway: its Fletcher terms are free;
     # XOR difference vs stored terms: all-zero == block verifies clean
-    mism_ref[...] = jnp.stack([a_old, b_old], axis=-1) ^ stored_ref[...]
-    a = jnp.sum(new, axis=-1, dtype=U32)
-    b = jnp.sum(new * w, axis=-1, dtype=U32)
-    ck_ref[...] = jnp.stack([a, b], axis=-1)
+    mism_ref[...] = _fletcher_terms(old) ^ stored_ref[...]
+    ck_ref[...] = _fletcher_terms(new)
+
+
+def _flat_call(kernel, operands, widths, interpret):
+    """Tile (n, width) u32 operands into TILE_BLOCKS-row grid steps.
+
+    `widths` lists the outputs' widths (block_words or 2 terms); every
+    output is (n, width) u32.
+    """
+    n = operands[0].shape[0]
+    tb, steps = row_tile(n, TILE_BLOCKS)
+    return pl.pallas_call(
+        kernel,
+        grid=(steps,),
+        in_specs=[_rows(tb, x.shape[1]) for x in operands],
+        out_specs=[_rows(tb, w) for w in widths],
+        out_shape=[jax.ShapeDtypeStruct((n, w), U32) for w in widths],
+        interpret=interpret,
+    )(*operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_commit(old: jax.Array, new: jax.Array, *, interpret: bool = False):
     """old/new: (n_blocks, block_words) u32 -> (delta, cksums)."""
     assert old.shape == new.shape and old.dtype == U32 == new.dtype
-    n, bw = old.shape
-    tb = _pick_tb(n)
-    return pl.pallas_call(
-        _fused_kernel,
-        grid=(n // tb,),
-        in_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                  pl.BlockSpec((tb, bw), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                   pl.BlockSpec((tb, 2), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32)],
-        interpret=interpret,
-    )(old, new)
+    return _flat_call(_fused_kernel, [old, new], [old.shape[1], 2],
+                      interpret)
 
 
 def _verify_call(old: jax.Array, new: jax.Array, stored: jax.Array,
@@ -99,21 +125,8 @@ def _verify_call(old: jax.Array, new: jax.Array, stored: jax.Array,
     assert old.shape == new.shape and old.dtype == U32 == new.dtype
     n, bw = old.shape
     assert stored.shape == (n, 2) and stored.dtype == U32, stored.shape
-    tb = _pick_tb(n)
-    return pl.pallas_call(
-        _fused_verify_kernel,
-        grid=(n // tb,),
-        in_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                  pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                  pl.BlockSpec((tb, 2), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                   pl.BlockSpec((tb, 2), lambda i: (i, 0)),
-                   pl.BlockSpec((tb, 2), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32)],
-        interpret=interpret,
-    )(old, new, stored)
+    return _flat_call(_fused_verify_kernel, [old, new, stored], [bw, 2, 2],
+                      interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -152,16 +165,10 @@ def _accum_kernel(acc_ref, old_ref, new_ref, acc_out_ref, old_ck_ref,
     # XOR deltas telescope: acc ^ (old ^ new) after W steps equals
     # row_epoch_start ^ row_now, the exact delta the epoch flush applies.
     acc_out_ref[...] = acc ^ old ^ new
-    bw = new.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
     # both tiles are in VMEM for the accumulate: their Fletcher terms are
     # free, and they are exactly what the incremental row digest needs
-    a_old = jnp.sum(old, axis=-1, dtype=U32)
-    b_old = jnp.sum(old * w, axis=-1, dtype=U32)
-    old_ck_ref[...] = jnp.stack([a_old, b_old], axis=-1)
-    a = jnp.sum(new, axis=-1, dtype=U32)
-    b = jnp.sum(new * w, axis=-1, dtype=U32)
-    new_ck_ref[...] = jnp.stack([a, b], axis=-1)
+    old_ck_ref[...] = _fletcher_terms(old)
+    new_ck_ref[...] = _fletcher_terms(new)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -180,86 +187,25 @@ def fused_accum_commit(acc: jax.Array, old: jax.Array, new: jax.Array, *,
     assert acc.shape == old.shape == new.shape, (acc.shape, old.shape,
                                                  new.shape)
     assert acc.dtype == old.dtype == new.dtype == U32
-    n, bw = old.shape
-    tb = _pick_tb(n)
-    return pl.pallas_call(
-        _accum_kernel,
-        grid=(n // tb,),
-        in_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                  pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                  pl.BlockSpec((tb, bw), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0)),
-                   pl.BlockSpec((tb, 2), lambda i: (i, 0)),
-                   pl.BlockSpec((tb, 2), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32)],
-        interpret=interpret,
-    )(acc, old, new)
+    return _flat_call(_accum_kernel, [acc, old, new], [old.shape[1], 2, 2],
+                      interpret)
 
 
 # ---------------------------------------------------------------------------
 # blockwise double-buffered streaming variants
 # ---------------------------------------------------------------------------
-# The flat kernels above hand whole-row tiles to the Pallas grid, which is
-# fine while n_blocks * block_words fits the automatic pipelining budget but
-# leaves the copy/compute overlap to the compiler.  The `*_stream` family
-# below owns the pipeline explicitly: operands live in ANY (HBM) memory, the
-# kernel streams them through a 2-deep VMEM ring with manual async copies —
-# chunk i+1's DMA is issued before chunk i's compute begins — and the
-# Fletcher digest of the whole row rides along as a loop-carried (A, B)
-# accumulator, so one sweep emits the delta, the per-block terms AND the
-# combined row digest (the flat path needs a separate `checksum.combine`
-# pass over the terms).  The ragged tail (n % chunk) is a statically-sized
-# epilogue chunk: DMA slice extents must be static, so the loop covers the
-# n // chunk full chunks and the remainder is one extra literal-size copy.
-
-
-def _stream_loop(n, cb, in_refs, bufs, sems, process, carry0):
-    """Double-buffered DMA stream over row-major (n, ...) HBM operands.
-
-    `bufs[j]` is a (2, cb, ...) VMEM ring for `in_refs[j]`; `sems` is a
-    (2, len(in_refs)) DMA semaphore grid.  `process(tiles, start, size,
-    carry)` sees the chunk's VMEM tiles and returns the updated carry.
-    """
-    nfull, tail = n // cb, n % cb
-
-    def copies(slot, start, size):
-        return [pltpu.make_async_copy(ref.at[pl.ds(start, size)],
-                                      buf.at[slot, pl.ds(0, size)],
-                                      sems.at[slot, j])
-                for j, (ref, buf) in enumerate(zip(in_refs, bufs))]
-
-    def start_chunk(slot, start, size):
-        for c in copies(slot, start, size):
-            c.start()
-
-    def wait_chunk(slot, start, size):
-        for c in copies(slot, start, size):
-            c.wait()
-
-    carry = carry0
-    if nfull:
-        start_chunk(0, 0, cb)
-
-        def body(ci, carry):
-            slot = jax.lax.rem(ci, 2)
-
-            @pl.when(ci + 1 < nfull)
-            def _prefetch():
-                start_chunk(1 - slot, (ci + 1) * cb, cb)
-
-            wait_chunk(slot, ci * cb, cb)
-            tiles = [buf[slot, pl.ds(0, cb)] for buf in bufs]
-            return process(tiles, ci * cb, cb, carry)
-
-        carry = jax.lax.fori_loop(0, nfull, body, carry)
-    if tail:
-        start_chunk(0, nfull * cb, tail)
-        wait_chunk(0, nfull * cb, tail)
-        tiles = [buf[0, pl.ds(0, tail)] for buf in bufs]
-        carry = process(tiles, nfull * cb, tail, carry)
-    return carry
+# The `*_stream` family walks the row in chunk_blocks-page chunks on a
+# sequential grid: Mosaic double-buffers every operand and result through
+# VMEM — chunk i+1's copy-in and chunk i-1's copy-out overlap chunk i's
+# compute — and the Fletcher digest of the whole row rides along as an
+# SMEM (A, B) accumulator, so one sweep emits the delta, the per-block
+# terms AND the combined row digest (the flat path needs a separate
+# `checksum.combine` pass over the terms).  The chunk copies are the
+# pipeline's own and not hand-written DMAs: a TPU DMA may not slice the
+# narrow (n, 2) term tables off their (8, 128) tiling, while the grid's
+# blocks carry them.  The ragged tail (n % chunk) is the grid's partial
+# last block: its padding rows are masked out of the digest by global
+# position and dropped on write-back.
 
 
 def _chunk_fletcher(x, start, n):
@@ -268,102 +214,114 @@ def _chunk_fletcher(x, start, n):
     The combine rule (core/checksum.py) weights block p's A term by the
     words after it, (n - 1 - p) * bw; positions are global, so a running
     (sum dA, sum dB) carry over chunks lands bit-identical to
-    `checksum.combine` over the full term table.
+    `checksum.combine` over the full term table.  The digest terms are
+    int32 (see `_fletcher_ab`); rows at or past n (a partial last chunk's
+    padding) contribute nothing.
     """
-    bw = x.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
-    a = jnp.sum(x, axis=-1, keepdims=True, dtype=U32)
-    b = jnp.sum(x * w, axis=-1, keepdims=True, dtype=U32)
-    pos = U32(start) + jax.lax.broadcasted_iota(U32, (x.shape[0], 1), 0)
-    after = (U32(n - 1) - pos) * U32(bw)
-    dig_a = jnp.sum(a, dtype=U32)
-    dig_b = jnp.sum(b + after * a, dtype=U32)
-    return jnp.concatenate([a, b], axis=-1), dig_a, dig_b
-
-
-def _stream_commit_kernel(old_hbm, new_hbm, delta_hbm, ck_hbm, dig_smem, *,
-                          n, cb):
-    bw = old_hbm.shape[1]
-
-    def scoped(obuf, nbuf, sems):
-        def process(tiles, start, size, carry):
-            o, nw = tiles
-            delta_hbm[pl.ds(start, size)] = o ^ nw
-            terms, da, db = _chunk_fletcher(nw, start, n)
-            ck_hbm[pl.ds(start, size)] = terms
-            return carry[0] + da, carry[1] + db
-
-        a, b = _stream_loop(n, cb, [old_hbm, new_hbm], [obuf, nbuf], sems,
-                            process, (U32(0), U32(0)))
-        dig_smem[0] = a
-        dig_smem[1] = b
-
-    pl.run_scoped(scoped,
-                  obuf=pltpu.VMEM((2, cb, bw), U32),
-                  nbuf=pltpu.VMEM((2, cb, bw), U32),
-                  sems=pltpu.SemaphoreType.DMA((2, 2)))
-
-
-def _stream_verify_kernel(old_hbm, new_hbm, stored_hbm, delta_hbm, ck_hbm,
-                          mism_hbm, dig_smem, *, n, cb):
-    bw = old_hbm.shape[1]
-
-    def scoped(obuf, nbuf, stbuf, sems):
-        def process(tiles, start, size, carry):
-            o, nw, st = tiles
-            delta_hbm[pl.ds(start, size)] = o ^ nw
-            oterms, _, _ = _chunk_fletcher(o, start, n)
-            mism_hbm[pl.ds(start, size)] = oterms ^ st
-            terms, da, db = _chunk_fletcher(nw, start, n)
-            ck_hbm[pl.ds(start, size)] = terms
-            return carry[0] + da, carry[1] + db
-
-        a, b = _stream_loop(n, cb, [old_hbm, new_hbm, stored_hbm],
-                            [obuf, nbuf, stbuf], sems, process,
-                            (U32(0), U32(0)))
-        dig_smem[0] = a
-        dig_smem[1] = b
-
-    pl.run_scoped(scoped,
-                  obuf=pltpu.VMEM((2, cb, bw), U32),
-                  nbuf=pltpu.VMEM((2, cb, bw), U32),
-                  stbuf=pltpu.VMEM((2, cb, 2), U32),
-                  sems=pltpu.SemaphoreType.DMA((2, 3)))
-
-
-def _stream_accum_kernel(acc_hbm, old_hbm, new_hbm, acc_out_hbm, old_ck_hbm,
-                         new_ck_hbm, dig_smem, *, n, cb):
-    bw = old_hbm.shape[1]
-
-    def scoped(abuf, obuf, nbuf, sems):
-        def process(tiles, start, size, carry):
-            ac, o, nw = tiles
-            acc_out_hbm[pl.ds(start, size)] = ac ^ o ^ nw
-            oterms, _, _ = _chunk_fletcher(o, start, n)
-            old_ck_hbm[pl.ds(start, size)] = oterms
-            terms, da, db = _chunk_fletcher(nw, start, n)
-            new_ck_hbm[pl.ds(start, size)] = terms
-            return carry[0] + da, carry[1] + db
-
-        a, b = _stream_loop(n, cb, [acc_hbm, old_hbm, new_hbm],
-                            [abuf, obuf, nbuf], sems, process,
-                            (U32(0), U32(0)))
-        dig_smem[0] = a
-        dig_smem[1] = b
-
-    pl.run_scoped(scoped,
-                  abuf=pltpu.VMEM((2, cb, bw), U32),
-                  obuf=pltpu.VMEM((2, cb, bw), U32),
-                  nbuf=pltpu.VMEM((2, cb, bw), U32),
-                  sems=pltpu.SemaphoreType.DMA((2, 3)))
+    a, b = _fletcher_ab(x)
+    rows, bw = x.shape
+    pos = start + jax.lax.broadcasted_iota(I32, (rows, 1), 0)
+    db = b + (I32(n - 1) - pos) * I32(bw) * a
+    if n % rows:
+        live = pos < n
+        a = jnp.where(live, a, 0)
+        db = jnp.where(live, db, 0)
+    return _u32(jnp.concatenate([a, b], axis=-1)), jnp.sum(a), jnp.sum(db)
 
 
 def _clamp_cb(chunk_blocks: int, n: int) -> int:
-    return max(1, min(int(chunk_blocks), n))
+    """Chunk height: whole 8-row tiles (at least one), or the whole row
+    when it is shorter."""
+    return row_tile(n, max(8, int(chunk_blocks) // 8 * 8))[0]
 
 
-_ANY = functools.partial(pl.BlockSpec, memory_space=pltpu.ANY)
 _SMEM = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+
+
+def _stream_call(body, n, cb, operands, in_specs, out_specs, out_shape,
+                interpret):
+    """Sequential cb-row chunk grid whose results end with the row digest.
+
+    `body(in_refs, out_refs, start)` fills one chunk's output blocks from
+    its input blocks and returns the chunk's (dA, dB) int32 digest terms
+    (`_chunk_fletcher`); the final (2,) u32 output is their sum.
+    """
+    steps = pl.cdiv(n, cb)
+    n_in = len(operands)
+
+    def kernel(*refs):
+        ins, outs = refs[:n_in], refs[n_in:-2]
+        dig_ref, acc_ref = refs[-2:]
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _init():
+            acc_ref[0] = I32(0)
+            acc_ref[1] = I32(0)
+
+        da, db = body(ins, outs, i * cb)
+        acc_ref[0] += da
+        acc_ref[1] += db
+
+        @pl.when(i == steps - 1)
+        def _emit():
+            dig_ref[0] = acc_ref[0].astype(U32)
+            dig_ref[1] = acc_ref[1].astype(U32)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(steps,),
+        in_specs=in_specs,
+        out_specs=list(out_specs) + [_SMEM()],
+        out_shape=list(out_shape) + [jax.ShapeDtypeStruct((2,), U32)],
+        scratch_shapes=[pltpu.SMEM((2,), I32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*operands)
+
+
+def _rows_call(body, operands, widths, chunk_blocks, interpret):
+    """`_stream_call` over (n, width) u32 operands and outputs."""
+    n = operands[0].shape[0]
+    cb = _clamp_cb(chunk_blocks, n)
+    return _stream_call(body, n, cb, operands,
+                       [_rows(cb, x.shape[1]) for x in operands],
+                       [_rows(cb, w) for w in widths],
+                       [jax.ShapeDtypeStruct((n, w), U32) for w in widths],
+                       interpret)
+
+
+def _commit_body(n):
+    def body(ins, outs, start):
+        old, new = ins[0][...], ins[1][...]
+        delta_ref, ck_ref = outs
+        delta_ref[...] = old ^ new
+        ck_ref[...], da, db = _chunk_fletcher(new, start, n)
+        return da, db
+    return body
+
+
+def _verify_body(n):
+    def body(ins, outs, start):
+        old, new = ins[0][...], ins[1][...]
+        delta_ref, ck_ref, mism_ref = outs
+        delta_ref[...] = old ^ new
+        mism_ref[...] = _fletcher_terms(old) ^ ins[2][...]
+        ck_ref[...], da, db = _chunk_fletcher(new, start, n)
+        return da, db
+    return body
+
+
+def _accum_body(n):
+    def body(ins, outs, start):
+        acc, old, new = (r[...] for r in ins)
+        acc_out_ref, old_ck_ref, new_ck_ref = outs
+        acc_out_ref[...] = acc ^ old ^ new
+        old_ck_ref[...] = _fletcher_terms(old)
+        new_ck_ref[...], da, db = _chunk_fletcher(new, start, n)
+        return da, db
+    return body
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_blocks", "interpret"))
@@ -373,33 +331,16 @@ def fused_commit_stream(old: jax.Array, new: jax.Array, *,
     """Streamed fused_commit: (delta, cksums, (A, B) row digest)."""
     assert old.shape == new.shape and old.dtype == U32 == new.dtype
     n, bw = old.shape
-    cb = _clamp_cb(chunk_blocks, n)
-    return pl.pallas_call(
-        functools.partial(_stream_commit_kernel, n=n, cb=cb),
-        in_specs=[_ANY(), _ANY()],
-        out_specs=[_ANY(), _ANY(), _SMEM()],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((2,), U32)],
-        interpret=interpret,
-    )(old, new)
+    return _rows_call(_commit_body(n), [old, new], [bw, 2], chunk_blocks,
+                      interpret)
 
 
 def _verify_stream_call(old, new, stored, chunk_blocks, interpret):
     assert old.shape == new.shape and old.dtype == U32 == new.dtype
     n, bw = old.shape
     assert stored.shape == (n, 2) and stored.dtype == U32, stored.shape
-    cb = _clamp_cb(chunk_blocks, n)
-    return pl.pallas_call(
-        functools.partial(_stream_verify_kernel, n=n, cb=cb),
-        in_specs=[_ANY(), _ANY(), _ANY()],
-        out_specs=[_ANY(), _ANY(), _ANY(), _SMEM()],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((2,), U32)],
-        interpret=interpret,
-    )(old, new, stored)
+    return _rows_call(_verify_body(n), [old, new, stored], [bw, 2, 2],
+                      chunk_blocks, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_blocks", "interpret"))
@@ -432,14 +373,5 @@ def fused_accum_commit_stream(acc: jax.Array, old: jax.Array,
                                                  new.shape)
     assert acc.dtype == old.dtype == new.dtype == U32
     n, bw = old.shape
-    cb = _clamp_cb(chunk_blocks, n)
-    return pl.pallas_call(
-        functools.partial(_stream_accum_kernel, n=n, cb=cb),
-        in_specs=[_ANY(), _ANY(), _ANY()],
-        out_specs=[_ANY(), _ANY(), _ANY(), _SMEM()],
-        out_shape=[jax.ShapeDtypeStruct((n, bw), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((2,), U32)],
-        interpret=interpret,
-    )(acc, old, new)
+    return _rows_call(_accum_body(n), [acc, old, new], [bw, 2, 2],
+                      chunk_blocks, interpret)

@@ -2,8 +2,8 @@
 
 This is the TPU analogue of Pangolin's ISA-L SIMD checksum loop (§3.5): a
 memory-bound sweep that reads each word once and produces two 32-bit
-accumulator lanes per 4 KB page block.  Tiling: TILE_BLOCKS pages per grid
-step, each (TILE_BLOCKS, block_words) u32 tile staged in VMEM;
+accumulator lanes per 4 KB page block.  Tiling: commit_fused.TILE_BLOCKS
+pages per grid step, each (TILE_BLOCKS, block_words) u32 tile in VMEM;
 block_words = 1024 = 8 x 128 keeps the lane dimension MXU/VPU-aligned.
 """
 from __future__ import annotations
@@ -11,59 +11,26 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-U32 = jnp.uint32
-TILE_BLOCKS = 8  # pages per grid step: 8 x 1024 x 4 B = 32 KB VMEM per input tile
+from repro.kernels import commit_fused as _cf
 
 
 def _fletcher_kernel(x_ref, out_ref):
-    x = x_ref[...]                                   # (tb, bw) u32
-    bw = x.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
-    a = jnp.sum(x, axis=-1, dtype=U32)
-    b = jnp.sum(x * w, axis=-1, dtype=U32)
-    out_ref[...] = jnp.stack([a, b], axis=-1)        # (tb, 2)
+    out_ref[...] = _cf._fletcher_terms(x_ref[...])   # (tb, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fletcher_blocks(blocks: jax.Array, *, interpret: bool = False
                     ) -> jax.Array:
     """blocks: (n_blocks, block_words) u32 -> (n_blocks, 2) u32."""
-    n, bw = blocks.shape
-    tb = min(TILE_BLOCKS, n)
-    assert n % tb == 0, (n, tb)
-    return pl.pallas_call(
-        _fletcher_kernel,
-        grid=(n // tb,),
-        in_specs=[pl.BlockSpec((tb, bw), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tb, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 2), U32),
-        interpret=interpret,
-    )(blocks)
+    return _cf._flat_call(_fletcher_kernel, [blocks], [2], interpret)[0]
 
 
-def _stream_fletcher_kernel(x_hbm, ck_hbm, dig_smem, *, n, cb):
-    from repro.kernels import commit_fused as _cf
-
-    bw = x_hbm.shape[1]
-
-    def scoped(xbuf, sems):
-        def process(tiles, start, size, carry):
-            terms, da, db = _cf._chunk_fletcher(tiles[0], start, n)
-            ck_hbm[pl.ds(start, size)] = terms
-            return carry[0] + da, carry[1] + db
-
-        a, b = _cf._stream_loop(n, cb, [x_hbm], [xbuf], sems, process,
-                                (U32(0), U32(0)))
-        dig_smem[0] = a
-        dig_smem[1] = b
-
-    pl.run_scoped(scoped,
-                  pltpu.VMEM((2, cb, bw), U32),
-                  pltpu.SemaphoreType.DMA((2, 1)))
+def _stream_fletcher_body(n):
+    def body(ins, outs, start):
+        outs[0][...], da, db = _cf._chunk_fletcher(ins[0][...], start, n)
+        return da, db
+    return body
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_blocks", "interpret"))
@@ -75,15 +42,5 @@ def fletcher_stream(blocks: jax.Array, *, chunk_blocks: int = 8,
     the digest rides the loop carry, so the flat path's separate
     `checksum.combine` pass over the term table disappears.
     """
-    from repro.kernels import commit_fused as _cf
-
-    n, bw = blocks.shape
-    cb = _cf._clamp_cb(chunk_blocks, n)
-    return pl.pallas_call(
-        functools.partial(_stream_fletcher_kernel, n=n, cb=cb),
-        in_specs=[_cf._ANY()],
-        out_specs=[_cf._ANY(), _cf._SMEM()],
-        out_shape=[jax.ShapeDtypeStruct((n, 2), U32),
-                   jax.ShapeDtypeStruct((2,), U32)],
-        interpret=interpret,
-    )(blocks)
+    return _cf._rows_call(_stream_fletcher_body(blocks.shape[0]), [blocks],
+                          [2], chunk_blocks, interpret)

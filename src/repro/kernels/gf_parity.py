@@ -42,11 +42,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import commit_fused as _cf
-from repro.kernels.tiling import largest_divisor_tile as _pick_tile
+from repro.kernels.tiling import row_tile
 
 U32 = jnp.uint32
 TILE_BLOCKS = 8
 TILE_ROWS = 512          # gf_scale tile height (matches xor_parity.py)
+# VMEM one `sdelta_stack` grid step may stage: its input tile and r output
+# planes, each double-buffered.  Half of v5e's 16 MiB default scoped VMEM,
+# leaving the compiler headroom.
+STACK_VMEM_BYTES = 8 << 20
+SLAB_ROWS = 8            # sdelta_stack rows weighted per inner step
 
 
 def _gf_mul_tile(x, coeff):
@@ -78,11 +83,11 @@ def gf_scale(x: jax.Array, coeff: jax.Array, *, interpret: bool = False
     if x.ndim == 1:
         x = x.reshape(-1, 1024) if x.size % 1024 == 0 else x.reshape(1, -1)
     n, m = x.shape
-    t = _pick_tile(n, TILE_ROWS)
+    t, steps = row_tile(n, TILE_ROWS)
     coeff = jnp.asarray(coeff, U32).reshape(1, 1)
     out = pl.pallas_call(
         _gf_scale_kernel,
-        grid=(n // t,),
+        grid=(steps,),
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
                   pl.BlockSpec((t, m), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((t, m), lambda i: (i, 0)),
@@ -95,14 +100,6 @@ def gf_scale(x: jax.Array, coeff: jax.Array, *, interpret: bool = False
 # ---------------------------------------------------------------------------
 # fused r-syndrome commit sweeps
 # ---------------------------------------------------------------------------
-
-def _fletcher_terms(x):
-    bw = x.shape[-1]
-    w = U32(bw) - jax.lax.broadcasted_iota(U32, (1, bw), 1)
-    a = jnp.sum(x, axis=-1, dtype=U32)
-    b = jnp.sum(x * w, axis=-1, dtype=U32)
-    return jnp.stack([a, b], axis=-1)
-
 
 def _make_s_kernel(r: int, verify: bool):
     """Kernel body: delta + r-1 weighted planes [+ verify] + checksums.
@@ -123,15 +120,15 @@ def _make_s_kernel(r: int, verify: bool):
         for k in range(1, r):
             sdelta_ref[k] = _gf_mul_tile(d, coeff_ref[k, 0])
         if verify:
-            mism_ref[...] = _fletcher_terms(old) ^ stored_ref[...]
-        ck_ref[...] = _fletcher_terms(new)
+            mism_ref[...] = _cf._fletcher_terms(old) ^ stored_ref[...]
+        ck_ref[...] = _cf._fletcher_terms(new)
     return kernel
 
 
 def _s_call(old, new, stored, coeffs, r, interpret):
     assert old.shape == new.shape and old.dtype == U32 == new.dtype
     n, bw = old.shape
-    tb = _pick_tile(n, TILE_BLOCKS)
+    tb, steps = row_tile(n, TILE_BLOCKS)
     coeffs = jnp.asarray(coeffs, U32).reshape(r, 1)
     verify = stored is not None
     in_specs = [pl.BlockSpec((r, 1), lambda i: (0, 0)),
@@ -150,7 +147,7 @@ def _s_call(old, new, stored, coeffs, r, interpret):
         out_shape.append(jax.ShapeDtypeStruct((n, 2), U32))
     return pl.pallas_call(
         _make_s_kernel(r, verify),
-        grid=(n // tb,),
+        grid=(steps,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -195,11 +192,34 @@ def fused_commit_old_terms_s(old: jax.Array, new: jax.Array,
 
 def _make_sdelta_stack_kernel(r: int):
     def kernel(coeff_ref, x_ref, o_ref):
-        x = x_ref[...]
-        o_ref[0] = x
-        for k in range(1, r):
-            o_ref[k] = _gf_mul_tile(x, coeff_ref[k, 0])
+        # Weight the tile 8 rows at a time: the clmul's temporaries then
+        # stay slab-sized, so the tile is bounded by its buffers alone.
+        def weigh(rows):
+            x = x_ref[rows]
+            o_ref[0, rows] = x
+            for k in range(1, r):
+                o_ref[k, rows] = _gf_mul_tile(x, coeff_ref[k, 0])
+
+        t = x_ref.shape[0]
+        if t % SLAB_ROWS:            # a whole short row: one slab
+            weigh(slice(None))
+            return
+
+        def slab(j, carry):
+            weigh(pl.ds(pl.multiple_of(j * SLAB_ROWS, SLAB_ROWS), SLAB_ROWS))
+            return carry
+
+        jax.lax.fori_loop(0, t // SLAB_ROWS, slab, 0)
     return kernel
+
+
+def _stack_tile_rows(m: int, r: int) -> int:
+    """Rows per `sdelta_stack` step that fit STACK_VMEM_BYTES (8-aligned).
+
+    Per row: the input and r output planes, double-buffered.
+    """
+    row_bytes = 4 * m * 2 * (1 + r)
+    return max(8, STACK_VMEM_BYTES // row_bytes // 8 * 8)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -219,11 +239,11 @@ def sdelta_stack(x: jax.Array, coeffs: jax.Array, *,
         x = x.reshape(-1, 1024) if x.size % 1024 == 0 else x.reshape(1, -1)
     n, m = x.shape
     r = coeffs.shape[0]
-    t = _pick_tile(n, TILE_ROWS)
+    t, steps = row_tile(n, _stack_tile_rows(m, r))
     coeffs = jnp.asarray(coeffs, U32).reshape(r, 1)
     out = pl.pallas_call(
         _make_sdelta_stack_kernel(r),
-        grid=(n // t,),
+        grid=(steps,),
         in_specs=[pl.BlockSpec((r, 1), lambda i: (0, 0)),
                   pl.BlockSpec((t, m), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((r, t, m), lambda i: (0, i, 0)),
@@ -237,56 +257,24 @@ def sdelta_stack(x: jax.Array, coeffs: jax.Array, *,
 # blockwise double-buffered streaming variants
 # ---------------------------------------------------------------------------
 # Same pipeline as commit_fused's streamed family (see the discussion
-# there): operands stay in HBM, a 2-deep VMEM ring double-buffers the
-# chunks, and the whole-row Fletcher digest rides the loop carry.  Each
-# resident delta chunk is weighted into all r syndrome planes before the
-# ring slot is recycled — one read of (old, new) regardless of redundancy.
+# there): a sequential chunk grid double-buffers operands and results
+# through VMEM, and the whole-row Fletcher digest rides an SMEM carry.  Each
+# resident delta chunk is weighted into all r syndrome planes before its
+# buffers are recycled — one read of (old, new) regardless of redundancy.
 
-def _make_stream_s_kernel(n, cb, r, verify):
-    def kernel(coeff_smem, old_hbm, new_hbm, *refs):
+def _stream_s_body(n, r, verify):
+    def body(ins, outs, start):
+        coeff_smem, old, new = ins[0], ins[1][...], ins[2][...]
+        sdelta_ref, ck_ref = outs[0], outs[1]
+        d = old ^ new
+        sdelta_ref[0] = d
+        for k in range(1, r):
+            sdelta_ref[k] = _gf_mul_tile(d, coeff_smem[k])
         if verify:
-            stored_hbm, sdelta_hbm, ck_hbm, mism_hbm, dig_smem = refs
-        else:
-            sdelta_hbm, ck_hbm, dig_smem = refs
-        bw = old_hbm.shape[1]
-
-        def scoped(*scratch):
-            if verify:
-                obuf, nbuf, stbuf, sems = scratch
-                in_refs = [old_hbm, new_hbm, stored_hbm]
-                bufs = [obuf, nbuf, stbuf]
-            else:
-                obuf, nbuf, sems = scratch
-                in_refs = [old_hbm, new_hbm]
-                bufs = [obuf, nbuf]
-
-            def process(tiles, start, size, carry):
-                o, nw = tiles[0], tiles[1]
-                d = o ^ nw
-                sdelta_hbm[0, pl.ds(start, size)] = d
-                for k in range(1, r):
-                    sdelta_hbm[k, pl.ds(start, size)] = _gf_mul_tile(
-                        d, coeff_smem[k])
-                if verify:
-                    oterms, _, _ = _cf._chunk_fletcher(o, start, n)
-                    mism_hbm[pl.ds(start, size)] = oterms ^ tiles[2]
-                terms, da, db = _cf._chunk_fletcher(nw, start, n)
-                ck_hbm[pl.ds(start, size)] = terms
-                return carry[0] + da, carry[1] + db
-
-            a, b = _cf._stream_loop(n, cb, in_refs, bufs, sems, process,
-                                    (U32(0), U32(0)))
-            dig_smem[0] = a
-            dig_smem[1] = b
-
-        scratch_shapes = [pltpu.VMEM((2, cb, bw), U32),
-                          pltpu.VMEM((2, cb, bw), U32)]
-        if verify:
-            scratch_shapes.append(pltpu.VMEM((2, cb, 2), U32))
-        scratch_shapes.append(
-            pltpu.SemaphoreType.DMA((2, 3 if verify else 2)))
-        pl.run_scoped(scoped, *scratch_shapes)
-    return kernel
+            outs[2][...] = _cf._fletcher_terms(old) ^ ins[3][...]
+        ck_ref[...], da, db = _cf._chunk_fletcher(new, start, n)
+        return da, db
+    return body
 
 
 def _s_stream_call(old, new, stored, coeffs, r, chunk_blocks, interpret):
@@ -295,26 +283,20 @@ def _s_stream_call(old, new, stored, coeffs, r, chunk_blocks, interpret):
     cb = _cf._clamp_cb(chunk_blocks, n)
     coeffs = jnp.asarray(coeffs, U32).reshape(r)
     verify = stored is not None
-    in_specs = [_cf._SMEM(), _cf._ANY(), _cf._ANY()]
+    rows = functools.partial(_cf._rows, cb)
+    in_specs = [_cf._SMEM(), rows(bw), rows(bw)]
     operands = [coeffs, old, new]
-    out_specs = [_cf._ANY(), _cf._ANY()]
+    out_specs = [pl.BlockSpec((r, cb, bw), lambda i: (0, i, 0)), rows(2)]
     out_shape = [jax.ShapeDtypeStruct((r, n, bw), U32),
                  jax.ShapeDtypeStruct((n, 2), U32)]
     if verify:
         assert stored.shape == (n, 2) and stored.dtype == U32, stored.shape
-        in_specs.append(_cf._ANY())
+        in_specs.append(rows(2))
         operands.append(stored)
-        out_specs.append(_cf._ANY())
+        out_specs.append(rows(2))
         out_shape.append(jax.ShapeDtypeStruct((n, 2), U32))
-    out_specs.append(_cf._SMEM())
-    out_shape.append(jax.ShapeDtypeStruct((2,), U32))
-    return pl.pallas_call(
-        _make_stream_s_kernel(n, cb, r, verify),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
+    return _cf._stream_call(_stream_s_body(n, r, verify), n, cb, operands,
+                           in_specs, out_specs, out_shape, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_blocks", "interpret"))

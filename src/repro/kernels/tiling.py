@@ -1,15 +1,15 @@
 """Shared tiling policy for the protection kernels.
 
 Every kernel in this package tiles a (n, m) u32 buffer along the leading
-axis; the grid must divide n exactly, so the tile height is the largest
-divisor of n no bigger than the kernel's VMEM-budget cap.
+axis.  A TPU block's row count must be a whole number of 8-row sublane
+tiles or the full axis, so the tile is min(n, cap) for a cap that is a
+multiple of 8, and the grid rounds up: a partial last block reads
+padding past row n, and its write-back drops those rows.
 """
 from __future__ import annotations
 
 
-def largest_divisor_tile(n: int, cap: int) -> int:
-    """Largest tile height <= cap that divides n exactly."""
+def row_tile(n: int, cap: int) -> tuple[int, int]:
+    """(tile rows, grid steps) for an n-row buffer and an 8-aligned cap."""
     t = min(cap, n)
-    while n % t:
-        t -= 1
-    return t
+    return t, -(-n // t)

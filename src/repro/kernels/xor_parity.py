@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import largest_divisor_tile
+from repro.kernels.tiling import row_tile
 
 U32 = jnp.uint32
 # (rows, lanes) tile: 512 x 1024 x 4 B = 2 MB per operand; 3 operands = 6 MB
@@ -26,10 +26,6 @@ def _xor2_kernel(a_ref, b_ref, o_ref):
     o_ref[...] = a_ref[...] ^ b_ref[...]
 
 
-def _pick_tile(n: int) -> int:
-    return largest_divisor_tile(n, TILE_ROWS)
-
-
 def _xor2(a: jax.Array, b: jax.Array, interpret: bool) -> jax.Array:
     assert a.shape == b.shape and a.dtype == U32 == b.dtype
     shape = a.shape
@@ -37,10 +33,10 @@ def _xor2(a: jax.Array, b: jax.Array, interpret: bool) -> jax.Array:
         a = a.reshape(-1, 1024) if a.size % 1024 == 0 else a.reshape(1, -1)
         b = b.reshape(a.shape)
     n, m = a.shape
-    t = _pick_tile(n)
+    t, steps = row_tile(n, TILE_ROWS)
     out = pl.pallas_call(
         _xor2_kernel,
-        grid=(n // t,),
+        grid=(steps,),
         in_specs=[pl.BlockSpec((t, m), lambda i: (i, 0)),
                   pl.BlockSpec((t, m), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((t, m), lambda i: (i, 0)),

@@ -89,7 +89,8 @@ def _analyze(lowered, compiled, n_devices: int, model_flops: float) -> dict:
         + memd["temp_size_in_bytes"] - memd["alias_size_in_bytes"])
     roof = hlo.roofline_terms(totals.flops, totals.hbm_bytes,
                               totals.total_wire_bytes,
-                              model_flops=model_flops / n_devices)
+                              model_flops=model_flops / n_devices,
+                              device_kind=hlo.V5E)
     return {
         "cost": {"flops": totals.flops, "hbm_bytes": totals.hbm_bytes,
                  "raw_hbm_bytes": totals.raw_hbm_bytes,

@@ -10,8 +10,8 @@ sum wire bytes for every collective with the standard volume conventions:
     all-to-all          (G-1)/G * operand_bytes
     collective-permute  operand_bytes
 
-Group size G is parsed from replica_groups when present.  v5e hardware
-constants for the three roofline terms live here too.
+Group size G is parsed from replica_groups when present.  The per-chip
+peaks for the three roofline terms live here too, keyed by device kind.
 """
 from __future__ import annotations
 
@@ -20,10 +20,34 @@ import math
 import re
 from typing import Dict, Optional
 
-# -- TPU v5e constants (per chip) -------------------------------------------
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link (assignment's constant)
+# -- published per-chip peaks, keyed by jax `device_kind` -------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # B/s
+    ici_bw: float               # B/s per link
+    source: str
+
+
+V5E = "TPU v5 lite"             # what jax reports for a TPU v5e chip
+
+PEAKS = {
+    # 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+    # interconnect over 4 ICI links = 50 GB/s per link
+    V5E: Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9,
+               source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of `device_kind`; a device not in PEAKS is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -154,10 +178,12 @@ class Roofline:
 
 
 def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float,
-                   model_flops: float = 0.0) -> Roofline:
-    ct = flops / PEAK_FLOPS_BF16
-    mt = hbm_bytes / HBM_BW
-    lt = wire_bytes / ICI_BW
+                   model_flops: float = 0.0, *,
+                   device_kind: str) -> Roofline:
+    peak = peaks(device_kind)
+    ct = flops / peak.flops_bf16
+    mt = hbm_bytes / peak.hbm_bw
+    lt = wire_bytes / peak.ici_bw
     bound = max((("compute", ct), ("memory", mt), ("collective", lt)),
                 key=lambda kv: kv[1])[0]
     return Roofline(

@@ -2,10 +2,12 @@
 
 Batched decode with Pangolin protection of the KV cache (the paper's
 atomic-style small-update case: incremental checksums + parity patches).
+The mesh is built from the devices JAX finds, as in `launch.train`.
 """
 import argparse
-import os
 import sys
+
+from repro.launch.train import force_host_devices
 
 
 def main(argv=None):
@@ -16,8 +18,6 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=32)
-    ap.add_argument("--data", type=int, default=4)
-    ap.add_argument("--model", type=int, default=2)
     ap.add_argument("--protect", default="mlpc")
     ap.add_argument("--redundancy", type=int, default=1,
                     choices=[1, 2, 3, 4],
@@ -33,7 +33,8 @@ def main(argv=None):
                     help="async commit ring depth: decode commits "
                          "dispatch up to this many verdicts ahead of "
                          "resolution (1 = resolve per token)")
-    ap.add_argument("--host-devices", type=int, default=8)
+    ap.add_argument("--host-devices", type=int, default=None,
+                    help="force this many CPU host devices")
     ap.add_argument("--metrics-dir", default=None,
                     help="publish the pool's metric registry "
                          "(server.prom + server.stats.json) here every "
@@ -48,21 +49,20 @@ def main(argv=None):
                          "(0 = OS-assigned; the bound port is printed)")
     args = ap.parse_args(argv)
 
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.host_devices}")
+    force_host_devices(args.host_devices)
 
     import time
 
     import jax
     from repro.configs.base import ProtectConfig
     from repro.configs.registry import get_config
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import device_mesh_shape, make_mesh
     from repro.models.transformer import build_model
     from repro.runtime.server import Server
 
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    enable_compile_cache()
+    mesh = make_mesh(device_mesh_shape(len(jax.devices())))
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg, mesh)
     params = model.init(jax.random.PRNGKey(0))

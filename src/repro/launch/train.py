@@ -1,15 +1,27 @@
 """Training launcher: `PYTHONPATH=src python -m repro.launch.train --arch <id>`.
 
 Runs the fault-tolerant training loop (runtime/trainer.py) for any
-registered architecture.  On real hardware the mesh comes from
-`jax.devices()` after `jax.distributed.initialize()`; on this container,
-`--host-devices N` forces N CPU host devices so the zone collectives run.
-Reduced configs (`--reduced`, default) train on CPU; full configs are for
-cluster use (the dry-run exercises them without allocation).
+registered architecture.  The mesh is built from the devices JAX finds
+(`launch.mesh.device_mesh_shape`): one TPU chip gives (1, 1), a four-chip
+host (4, 1).  On CPU, `--host-devices N` forces N host devices so the zone
+collectives run (8 give (4, 2)).  Reduced configs (`--reduced`, default)
+train on CPU; full configs need the chip.
 """
 import argparse
 import os
 import sys
+
+
+def force_host_devices(n) -> None:
+    """Ask XLA for `n` CPU host devices (None: leave the devices alone).
+
+    Must run before JAX first initializes; an XLA_FLAGS that already
+    sets the count wins.
+    """
+    flags = os.environ.get("XLA_FLAGS", "")
+    if n and "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}")
 
 
 def main(argv=None):
@@ -20,8 +32,6 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
-    ap.add_argument("--data", type=int, default=4, help="data-axis size")
-    ap.add_argument("--model", type=int, default=2, help="model-axis size")
     ap.add_argument("--protect", default="mlpc",
                     choices=["none", "ml", "mlp", "mlpc", "replica",
                              "mlp2", "mlpc2"])
@@ -48,7 +58,8 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
-    ap.add_argument("--host-devices", type=int, default=8)
+    ap.add_argument("--host-devices", type=int, default=None,
+                    help="force this many CPU host devices")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-dir", default=None,
                     help="publish the pool's metric registry "
@@ -64,20 +75,17 @@ def main(argv=None):
                          "(0 = OS-assigned; the bound port is printed)")
     args = ap.parse_args(argv)
 
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.host_devices}")
+    force_host_devices(args.host_devices)
 
     import jax
     from repro.configs.base import ProtectConfig, TrainConfig
     from repro.configs.registry import get_config
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import device_mesh_shape, make_mesh
     from repro.runtime.trainer import Trainer
 
-    n_dev = len(jax.devices())
-    data = min(args.data, n_dev // args.model)
-    mesh = jax.make_mesh((data, args.model), ("data", "model"))
+    enable_compile_cache()
+    mesh = make_mesh(device_mesh_shape(len(jax.devices())))
     cfg = get_config(args.arch, reduced=args.reduced)
     trainer = Trainer(
         cfg,

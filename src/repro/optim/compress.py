@@ -20,9 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 PyTree = Any

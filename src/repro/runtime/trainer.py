@@ -32,6 +32,7 @@ replay-verifiable mid-window).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 from typing import Optional
@@ -139,12 +140,14 @@ class Trainer(PoolHost):
 
     def initialize(self, key=None) -> None:
         key = key if key is not None else jax.random.PRNGKey(self.seed)
-        state = api.init_train_state(self.model, self.optimizer, key)
-        state = jax.device_put(
-            state, jax.tree.map(
-                lambda s: NamedSharding(self.mesh, s),
-                api.train_state_specs(self.model, self.optimizer, self.mesh),
-                is_leaf=lambda x: isinstance(x, P)))
+        # created sharded in one program: no device stages the whole state
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s),
+            api.train_state_specs(self.model, self.optimizer, self.mesh),
+            is_leaf=lambda x: isinstance(x, P))
+        state = jax.jit(functools.partial(api.init_train_state, self.model,
+                                          self.optimizer),
+                        out_shardings=shardings)(key)
         self.pool.init(state)
         self._host_step = 0
 

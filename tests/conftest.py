@@ -10,16 +10,6 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import sys
-
-try:                             # the container image may not ship hypothesis
-    import hypothesis            # noqa: F401
-except ImportError:
-    from tests import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub
-    _hypothesis_stub.strategies = _hypothesis_stub
-
 import dataclasses
 
 import jax
@@ -28,23 +18,25 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 
 @pytest.fixture(scope="session")
 def mesh42() -> Mesh:
     """4-way data (zone) axis x 2-way model axis."""
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2))
 
 
 @pytest.fixture(scope="session")
 def mesh81() -> Mesh:
     """8-way data axis (pure zone; power of two for tree reduce)."""
-    return jax.make_mesh((8, 1), ("data", "model"))
+    return make_mesh((8, 1))
 
 
 @pytest.fixture(scope="session")
 def mesh_pod() -> Mesh:
     """Tiny multi-pod mesh (2 pods x 2 data x 2 model)."""
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def small_state(mesh):

@@ -82,20 +82,34 @@ def test_parse_hlo_finds_entry():
 
 
 def test_roofline_terms_bound_selection():
-    r = hlo_analysis.roofline_terms(flops=1e15, hbm_bytes=1e9, wire_bytes=1e6)
+    v5e = hlo_analysis.V5E
+    r = hlo_analysis.roofline_terms(flops=1e15, hbm_bytes=1e9, wire_bytes=1e6,
+                                    device_kind=v5e)
     assert r.bound == "compute"
-    r = hlo_analysis.roofline_terms(flops=1e9, hbm_bytes=1e13, wire_bytes=1e6)
+    r = hlo_analysis.roofline_terms(flops=1e9, hbm_bytes=1e13, wire_bytes=1e6,
+                                    device_kind=v5e)
     assert r.bound == "memory"
-    r = hlo_analysis.roofline_terms(flops=1e9, hbm_bytes=1e9, wire_bytes=1e13)
+    r = hlo_analysis.roofline_terms(flops=1e9, hbm_bytes=1e9, wire_bytes=1e13,
+                                    device_kind=v5e)
     assert r.bound == "collective"
-    r = hlo_analysis.roofline_terms(1e12, 1e9, 1e6, model_flops=5e11)
+    r = hlo_analysis.roofline_terms(1e12, 1e9, 1e6, model_flops=5e11,
+                                    device_kind=v5e)
     assert r.useful_ratio == pytest.approx(0.5)
+
+
+def test_peaks_keyed_by_device_kind():
+    """v5e peaks carry their source; a device with none is an error."""
+    p = hlo_analysis.peaks(hlo_analysis.V5E)
+    assert (p.flops_bf16, p.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in p.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        hlo_analysis.roofline_terms(1e9, 1e9, 1e9, device_kind="cpu")
 
 
 def test_collective_parsing_shard_map(mesh42):
     """psum inside shard_map must be seen as an all-reduce with wire bytes
     2 (G-1)/G * payload."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = 1024
@@ -115,7 +129,7 @@ def test_collective_parsing_shard_map(mesh42):
 
 
 def test_collective_parsing_all_gather(mesh42):
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(x):

@@ -24,9 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import checksum as cksum
 from repro.core import gf
 from repro.core.epoch import DeferredProtector
