@@ -29,6 +29,7 @@ the CPU) and when any phase fails.  The last line of stdout is
 The times it prints are smoke timings, not metrics.
 """
 import argparse
+import functools
 import gc
 import json
 import math
@@ -122,58 +123,53 @@ def kernel_phase(seed: int, n: int = N_PAGES, bw: int = PAGE_WORDS) -> None:
     def per_tenant(x):
         return x.reshape((tenants, n // tenants) + x.shape[1:])
 
-    def tb_oracle():
-        outs = [ref.fused_verify_commit_s_ref(o, w, s, co3)
-                for o, w, s in zip(per_tenant(old), per_tenant(new),
-                                   per_tenant(stored))]
-        return (jnp.stack([sd.reshape(3, -1) for sd, _, _ in outs]),
+    def tb_oracle(old, new, stored, co):       # one oracle call per tenant
+        outs = [ref.fused_verify_commit_s_ref(o, w, s, co)
+                for o, w, s in zip(old, new, stored)]
+        return (jnp.stack([sd.reshape(co.shape[0], -1) for sd, _, _ in outs]),
                 jnp.stack([c for _, c, _ in outs]),
                 jnp.stack([b for _, _, b in outs]))
 
-    ragged = n - 5                      # a partial last streamed chunk
+    def tail(*xs):                      # a partial last streamed chunk
+        return tuple(x[:n - 5] for x in xs)
+
+    stream = functools.partial(ops.fused_verify_commit_s_stream,
+                               chunk_blocks=cb)
+
+    def r1(entry):                      # engines pass no coefficients at r=1
+        return lambda old, new, stored, _: entry(old, new, stored)
+
+    # (name, kernel entry, its oracle, operands); the operands are jit
+    # arguments, never closed-over constants
     cases = [
-        ("fused_verify_commit_s r=1",
-         lambda: ops.fused_verify_commit_s(old, new, stored),
-         lambda: ref.fused_verify_commit_s_ref(old, new, stored, one)),
-        ("fused_verify_commit_s r=3",
-         lambda: ops.fused_verify_commit_s(old, new, stored, co3),
-         lambda: ref.fused_verify_commit_s_ref(old, new, stored, co3)),
-        ("fused_verify_commit_s_stream r=1",
-         lambda: ops.fused_verify_commit_s_stream(old, new, stored,
-                                                  chunk_blocks=cb),
-         lambda: ref.fused_verify_commit_s_stream_ref(old, new, stored, one)),
-        ("fused_verify_commit_s_stream r=3",
-         lambda: ops.fused_verify_commit_s_stream(old, new, stored, co3,
-                                                  chunk_blocks=cb),
-         lambda: ref.fused_verify_commit_s_stream_ref(old, new, stored,
-                                                      co3)),
-        ("fused_verify_commit_s_stream r=3, ragged tail",
-         lambda: ops.fused_verify_commit_s_stream(
-             old[:ragged], new[:ragged], stored[:ragged], co3,
-             chunk_blocks=cb),
-         lambda: ref.fused_verify_commit_s_stream_ref(
-             old[:ragged], new[:ragged], stored[:ragged], co3)),
-        ("fused_accum_commit",
-         lambda: ops.fused_accum_commit(acc, old, new),
-         lambda: ref.fused_accum_commit_ref(acc, old, new)),
+        ("fused_verify_commit_s r=1", r1(ops.fused_verify_commit_s),
+         ref.fused_verify_commit_s_ref, (old, new, stored, one)),
+        ("fused_verify_commit_s r=3", ops.fused_verify_commit_s,
+         ref.fused_verify_commit_s_ref, (old, new, stored, co3)),
+        ("fused_verify_commit_s_stream r=1", r1(stream),
+         ref.fused_verify_commit_s_stream_ref, (old, new, stored, one)),
+        ("fused_verify_commit_s_stream r=3", stream,
+         ref.fused_verify_commit_s_stream_ref, (old, new, stored, co3)),
+        ("fused_verify_commit_s_stream r=3, ragged tail", stream,
+         ref.fused_verify_commit_s_stream_ref,
+         tail(old, new, stored) + (co3,)),
+        ("fused_accum_commit", ops.fused_accum_commit,
+         ref.fused_accum_commit_ref, (acc, old, new)),
         ("fused_accum_commit_stream",
-         lambda: ops.fused_accum_commit_stream(acc, old, new,
-                                               chunk_blocks=cb),
-         lambda: ref.fused_accum_commit_stream_ref(acc, old, new)),
+         functools.partial(ops.fused_accum_commit_stream, chunk_blocks=cb),
+         ref.fused_accum_commit_stream_ref, (acc, old, new)),
         ("fletcher_stream",
-         lambda: ops.fletcher_stream(new, chunk_blocks=cb),
-         lambda: ref.fletcher_stream_ref(new)),
-        ("syndrome_scale r=3 (sdelta_stack)",
-         lambda: ops.syndrome_scale(delta, co3),
-         lambda: ref.sdelta_stack_ref(delta, co3)),
+         functools.partial(ops.fletcher_stream, chunk_blocks=cb),
+         ref.fletcher_stream_ref, (new,)),
+        ("syndrome_scale r=3 (sdelta_stack)", ops.syndrome_scale,
+         ref.sdelta_stack_ref, (delta, co3)),
         ("fused_verify_commit_s_tb r=3, 4 tenants",
-         lambda: ops.fused_verify_commit_s_tb(
-             per_tenant(old), per_tenant(new), per_tenant(stored), co3),
-         jax.jit(tb_oracle)),
+         ops.fused_verify_commit_s_tb, tb_oracle,
+         (per_tenant(old), per_tenant(new), per_tenant(stored), co3)),
     ]
-    for name, kernel, oracle in cases:
-        got = jax.block_until_ready(kernel())
-        want = jax.jit(oracle)()
+    for name, kernel, oracle, operands in cases:
+        got = jax.block_until_ready(kernel(*operands))
+        want = jax.jit(oracle)(*operands)
         check(bits_equal(got, want), f"{name}: kernel != kernels/ref.py")
         log(f"kernel {name}: bit-identical to its oracle "
             f"({n} x {bw} u32 row)")
