@@ -23,10 +23,13 @@ exemplars).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Callable, List, Optional
 
 import jax
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def _scalar_ready(ok: Any) -> bool:
@@ -44,14 +47,18 @@ class CommitTicket:
     optional `extras` (e.g. per-tenant verdict scalars for a tenancy
     wave).  `result()` fetches the verdict — blocking unless the scalar
     already landed — and fires the pool's resolve callback exactly
-    once; `ready()` polls without blocking.  `void()` resolves the
-    ticket deterministically WITHOUT trusting the device value (the
-    recovery path's option for tickets whose commit a re-arm
-    superseded).
+    once; `ready()` polls without blocking.  With a `tracer`, the fetch
+    is its `pool.resolve` phase, carrying `step` (the commit's data
+    cursor): whichever path resolves the ticket (its caller, the
+    ring's back-pressure, a drain), the host's wait shows on the
+    profiler's clock.  `void()` resolves the ticket deterministically
+    WITHOUT trusting the device value (the recovery path's option for
+    tickets whose commit a re-arm superseded).
     """
 
     __slots__ = ("seq", "ok", "dispatched_at", "resolved_at", "span_id",
-                 "extras", "staged", "voided", "_verdict", "_on_resolve")
+                 "extras", "staged", "voided", "step", "_verdict",
+                 "_on_resolve", "_tracer")
 
     def __init__(self, seq: int, ok: Any, *,
                  dispatched_at: Optional[float] = None,
@@ -59,7 +66,8 @@ class CommitTicket:
                  extras: Optional[dict] = None,
                  staged: bool = False,
                  on_resolve: Optional[Callable[["CommitTicket"], None]]
-                 = None):
+                 = None,
+                 tracer: Any = None, step: Any = None):
         self.seq = int(seq)
         self.ok = ok
         self.dispatched_at = (time.perf_counter() if dispatched_at is None
@@ -72,8 +80,10 @@ class CommitTicket:
         # resolution for these)
         self.staged = bool(staged)
         self.voided = False
+        self.step = step
         self._verdict: Optional[bool] = None
         self._on_resolve = on_resolve
+        self._tracer = tracer
 
     # -- state -----------------------------------------------------------------
 
@@ -103,7 +113,10 @@ class CommitTicket:
             return self._verdict
         if not block and not _scalar_ready(self.ok):
             return None
-        self._finish(bool(jax.device_get(self.ok)))
+        with (_NO_SPAN if self._tracer is None else
+              self._tracer.phase("pool.resolve", step=self.step)):
+            verdict = bool(jax.device_get(self.ok))
+        self._finish(verdict)
         return self._verdict
 
     def void(self, verdict: bool = False) -> bool:

@@ -10,7 +10,13 @@ perturb a compiled program (the §facade zero-byte invariant):
   * `Tracer` (obs/trace.py) — structured JSONL span events whose ids
     tie a fault injection to its recovery solve, re-verify and queued
     follow-ups; `validate_events` checks well-formedness
-    (scripts/trace_check.py is the CLI).
+    (scripts/trace_check.py is the CLI).  Its second sink is the
+    profiler: handed an annotation factory (the Pool hands in
+    `jax.profiler.TraceAnnotation`), every span is also a `pool.<kind>`
+    profiler span, and `Tracer.phase` marks the step path's boundaries
+    (`trainer.dispatch`, `pool.commit_dispatch`, `pool.resolve`, ...)
+    on the profiler's clock alone, where they line up with the device's
+    operations.
   * `HealthReport` (obs/health.py) — green/degraded/critical from the
     window state, straggler drops, scrub findings and syndrome budget;
     `prometheus_text` (obs/export.py) renders the registry for scraping.

@@ -18,8 +18,19 @@ Ids are monotonically increasing per tracer; `ts` is host
 perf_counter-relative seconds (monotonic within one trace — the point
 is ordering and duration, not wall-clock epoch).  With a `path`, every
 event is appended to the JSONL file as it happens (crash traces stay
-useful); the in-memory `events` list always accumulates, which is what
-tests and `validate_events` consume.
+useful); the in-memory `events` list accumulates every event, which is
+what tests and `validate_events` consume.  `emit_hot` is the one
+exception: a point event raised on every commit is recorded only where
+a file is written, so a tracer without one holds no per-commit events.
+
+A second sink puts the same spans on the profiler's clock, beside the
+device's operations: with an `annotation` factory (the Pool hands in
+`jax.profiler.TraceAnnotation`; this module stays jax-free), every
+`span(kind)` also opens a profiler annotation named `pool.<kind>`, and
+`phase(name, **ids)` opens only the annotation — nothing in the file,
+nothing in memory — for the step path's hot boundaries.  A profiler
+that is not running records nothing, so a phase costs one context
+manager; the profiler decides what is kept.
 
 `validate_events` is the single source of truth for trace well-formedness
 — scripts/trace_check.py is a thin CLI over it:
@@ -30,10 +41,13 @@ tests and `validate_events` consume.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
-from typing import IO, List, Optional
+from typing import IO, Callable, List, Optional
+
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 class Tracer:
@@ -48,14 +62,20 @@ class Tracer:
     scripts/trace_check.py validates a rotated family as ONE logical
     event stream.  The in-memory `events` list is unaffected by
     rotation; `segments` lists the files written so far.
+
+    `annotation(name, **ids)` is a context-manager constructor that
+    opens a span on the profiler's timeline; None keeps the tracer to
+    its JSONL sink.
     """
 
     def __init__(self, path: Optional[str] = None, *,
                  rotate_lines: Optional[int] = None,
-                 rotate_bytes: Optional[int] = None):
+                 rotate_bytes: Optional[int] = None,
+                 annotation: Optional[Callable] = None):
         assert rotate_lines is None or rotate_lines > 0
         assert rotate_bytes is None or rotate_bytes > 0
         self.path = path
+        self.annotation = annotation
         self.rotate_lines = rotate_lines
         self.rotate_bytes = rotate_bytes
         self.segments: List[str] = []
@@ -116,6 +136,17 @@ class Tracer:
         """One point event; returns its id (faults hand this to spans)."""
         return self._write(self._fresh("point", kind, fields))["id"]
 
+    def emit_hot(self, kind: str, **fields) -> int:
+        """A point event raised on every commit: recorded as `emit`
+        records it when the tracer writes a file, otherwise only its id
+        is taken, so ids (and the exemplars that carry them) are the
+        same either way and memory does not grow with the run."""
+        if self._fh is None:
+            eid = self._next_id
+            self._next_id += 1
+            return eid
+        return self.emit(kind, **fields)
+
     def begin(self, kind: str, **fields) -> int:
         """Open a span; close it with `end(span_id, ...)`."""
         return self._write(self._fresh("begin", kind, fields))["id"]
@@ -127,8 +158,19 @@ class Tracer:
 
     def span(self, kind: str, **fields) -> "_Span":
         """Context manager: begin on enter, end on exit (an exception
-        ends the span with error=<type> and propagates)."""
+        ends the span with error=<type> and propagates).  With an
+        annotation factory the span is also a profiler span named
+        `pool.<kind>` carrying the span's id."""
         return _Span(self, kind, fields)
+
+    def phase(self, name: str, **ids):
+        """A profiler span only (no event, nothing kept): the step
+        path's boundaries, `name` as the profiler shows it and `ids`
+        (host values; `step=<cursor>` on the step path) as its
+        arguments.  Without an annotation factory, a no-op."""
+        if self.annotation is None:
+            return _NO_ANNOTATION
+        return self.annotation(name, **ids)
 
     def close(self) -> None:
         if self._fh is not None:
@@ -143,6 +185,7 @@ class _Span:
         self.fields = fields
         self.id: Optional[int] = None
         self.end_fields: dict = {}
+        self._mark = _NO_ANNOTATION
 
     def annotate(self, **fields) -> None:
         """Attach fields to the span's end event."""
@@ -150,9 +193,12 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self.id = self.tracer.begin(self.kind, **self.fields)
+        self._mark = self.tracer.phase(f"pool.{self.kind}", id=self.id)
+        self._mark.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._mark.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.end_fields.setdefault("error", exc_type.__name__)
         self.tracer.end(self.id, self.kind, **self.end_fields)

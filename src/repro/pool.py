@@ -51,6 +51,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ProtectConfig
 from repro.core import microbuffer
@@ -291,6 +292,7 @@ class Pool(EngineHost):
         self.metrics = (metrics if metrics is not None
                         else MetricsRegistry())
         self.tracer = tracer if tracer is not None else Tracer()
+        self.tracer.annotation = TraceAnnotation
         self._open_kw = dict(data_axis=data_axis,
                              dirty_leaf_idx=dirty_leaf_idx,
                              dirty_capacity=dirty_capacity,
@@ -574,37 +576,40 @@ class Pool(EngineHost):
         feeds the right one to the engine it built.
         """
         assert self.prot is not None, "Pool.commit before init()"
-        t0 = time.perf_counter()
-        if self._engine is not None:
-            assert not verify_old, \
-                "verify_old is a synchronous-engine feature (window=1)"
-            self._est, ok = self._engine.commit(
-                self._est, state_new, dirty_words=dirty_words,
-                data_cursor=data_cursor, rng_key=rng_key,
-                canary_ok=canary_ok)
-        else:
-            self._prot, ok = self.protector.commit(
-                self._prot, state_new, dirty_pages=dirty_pages,
-                verify_old=verify_old, donate=self.donate,
-                data_cursor=data_cursor, rng_key=rng_key,
-                canary_ok=canary_ok)
-            if self._arrival_fn is not None:
-                # synchronous cadence: every commit is its own window
-                # boundary, so the arrival point is right after it
-                new = self._arrival_fn(self._prot, 1, True)
-                if new is not None:
-                    self._prot = new
-        # the scrub cadence + clean-streak window growth ride on the
-        # host-known canary verdict (no device sync on the hot path)
-        self.scrubber.on_commit(clean=bool(canary_ok))
-        # telemetry: the observed wall is DISPATCH wall — commits return
-        # a device verdict unfetched, so this measures the host cost of
-        # launching the program, which is exactly what instrumentation
-        # could perturb (the device-side cost is the benchmarks' job)
-        self._m_commits.inc()
-        if not canary_ok:
-            self._m_aborted.inc()
-        self._m_commit_ms.observe((time.perf_counter() - t0) * 1e3)
+        with self.tracer.phase("pool.commit_dispatch", step=data_cursor):
+            t0 = time.perf_counter()
+            if self._engine is not None:
+                assert not verify_old, \
+                    "verify_old is a synchronous-engine feature (window=1)"
+                self._est, ok = self._engine.commit(
+                    self._est, state_new, dirty_words=dirty_words,
+                    data_cursor=data_cursor, rng_key=rng_key,
+                    canary_ok=canary_ok)
+            else:
+                self._prot, ok = self.protector.commit(
+                    self._prot, state_new, dirty_pages=dirty_pages,
+                    verify_old=verify_old, donate=self.donate,
+                    data_cursor=data_cursor, rng_key=rng_key,
+                    canary_ok=canary_ok)
+                if self._arrival_fn is not None:
+                    # synchronous cadence: every commit is its own
+                    # window boundary, so the arrival point is right
+                    # after it
+                    new = self._arrival_fn(self._prot, 1, True)
+                    if new is not None:
+                        self._prot = new
+            # the scrub cadence + clean-streak window growth ride on the
+            # host-known canary verdict (no device sync on the hot path)
+            self.scrubber.on_commit(clean=bool(canary_ok))
+            # telemetry: the observed wall is DISPATCH wall — commits
+            # return a device verdict unfetched, so this measures the host
+            # cost of launching the program, which is exactly what
+            # instrumentation could perturb (the device-side cost is the
+            # benchmarks' job)
+            self._m_commits.inc()
+            if not canary_ok:
+                self._m_aborted.inc()
+            self._m_commit_ms.observe((time.perf_counter() - t0) * 1e3)
         return ok
 
     # -- async commit pipeline ---------------------------------------------------
@@ -647,57 +652,59 @@ class Pool(EngineHost):
         Routing (`dirty_pages` vs `dirty_words`) matches `commit`.
         """
         assert self.prot is not None, "Pool.commit_async before init()"
-        t0 = time.perf_counter()
-        staged = not isinstance(canary_ok, (bool, np.bool_))
-        if self._engine is not None:
-            assert not verify_old, \
-                "verify_old is a synchronous-engine feature (window=1)"
-            if staged:
-                self._est, ok = self._engine.commit_staged(
-                    self._est, state_new, canary=canary_ok,
-                    dirty_words=dirty_words, data_cursor=data_cursor,
-                    rng_key=rng_key)
+        with self.tracer.phase("pool.commit_dispatch", step=data_cursor):
+            t0 = time.perf_counter()
+            staged = not isinstance(canary_ok, (bool, np.bool_))
+            if self._engine is not None:
+                assert not verify_old, \
+                    "verify_old is a synchronous-engine feature (window=1)"
+                if staged:
+                    self._est, ok = self._engine.commit_staged(
+                        self._est, state_new, canary=canary_ok,
+                        dirty_words=dirty_words, data_cursor=data_cursor,
+                        rng_key=rng_key)
+                else:
+                    self._est, ok = self._engine.commit(
+                        self._est, state_new, dirty_words=dirty_words,
+                        data_cursor=data_cursor, rng_key=rng_key,
+                        canary_ok=bool(canary_ok))
             else:
-                self._est, ok = self._engine.commit(
-                    self._est, state_new, dirty_words=dirty_words,
-                    data_cursor=data_cursor, rng_key=rng_key,
-                    canary_ok=bool(canary_ok))
-        else:
-            if staged:
-                # no donation: the old state is the select's False arm
-                prot_old = self._prot
-                prot_new, ok_c = self.protector.commit(
-                    prot_old, state_new, dirty_pages=dirty_pages,
-                    verify_old=verify_old, donate=False,
-                    data_cursor=data_cursor, rng_key=rng_key,
-                    canary_ok=True)
-                self._prot, ok = self._staged_sel_fn()(
-                    canary_ok, ok_c, prot_new, prot_old)
-            else:
-                self._prot, ok = self.protector.commit(
-                    self._prot, state_new, dirty_pages=dirty_pages,
-                    verify_old=verify_old, donate=self.donate,
-                    data_cursor=data_cursor, rng_key=rng_key,
-                    canary_ok=bool(canary_ok))
-            if self._arrival_fn is not None:
-                new = self._arrival_fn(self._prot, 1, True)
-                if new is not None:
-                    self._prot = new
-        seq = self._ticket_seq
-        self._ticket_seq += 1
-        span_id = self.tracer.emit("commit_dispatch", seq=seq,
-                                   staged=bool(staged))
-        if not staged:
-            # host-known canary: dispatch-time bookkeeping identical to
-            # the synchronous commit path
-            self.scrubber.on_commit(clean=bool(canary_ok))
-            if not canary_ok:
-                self._m_aborted.inc()
-        self._m_commits.inc()
-        self._m_commit_ms.observe((time.perf_counter() - t0) * 1e3)
+                if staged:
+                    # no donation: the old state is the select's False arm
+                    prot_old = self._prot
+                    prot_new, ok_c = self.protector.commit(
+                        prot_old, state_new, dirty_pages=dirty_pages,
+                        verify_old=verify_old, donate=False,
+                        data_cursor=data_cursor, rng_key=rng_key,
+                        canary_ok=True)
+                    self._prot, ok = self._staged_sel_fn()(
+                        canary_ok, ok_c, prot_new, prot_old)
+                else:
+                    self._prot, ok = self.protector.commit(
+                        self._prot, state_new, dirty_pages=dirty_pages,
+                        verify_old=verify_old, donate=self.donate,
+                        data_cursor=data_cursor, rng_key=rng_key,
+                        canary_ok=bool(canary_ok))
+                if self._arrival_fn is not None:
+                    new = self._arrival_fn(self._prot, 1, True)
+                    if new is not None:
+                        self._prot = new
+            seq = self._ticket_seq
+            self._ticket_seq += 1
+            span_id = self.tracer.emit_hot("commit_dispatch", seq=seq,
+                                           staged=bool(staged))
+            if not staged:
+                # host-known canary: dispatch-time bookkeeping identical
+                # to the synchronous commit path
+                self.scrubber.on_commit(clean=bool(canary_ok))
+                if not canary_ok:
+                    self._m_aborted.inc()
+            self._m_commits.inc()
+            self._m_commit_ms.observe((time.perf_counter() - t0) * 1e3)
         return self._ring.submit(CommitTicket(
             seq, ok, dispatched_at=t0, span_id=span_id, extras=extras,
-            staged=staged, on_resolve=self._on_ticket_resolved))
+            staged=staged, on_resolve=self._on_ticket_resolved,
+            tracer=self.tracer, step=data_cursor))
 
     def _on_ticket_resolved(self, ticket: CommitTicket) -> None:
         """Resolution bookkeeping (fires exactly once per ticket): the
@@ -848,6 +855,7 @@ class Pool(EngineHost):
         """Swap the trace sink (e.g. for a file-backed tracer after the
         pool was built) — threaded through `_open_kw` so pools built by
         `rescale` keep emitting into the new sink."""
+        tracer.annotation = TraceAnnotation
         self.tracer = tracer
         self._open_kw["tracer"] = tracer
 

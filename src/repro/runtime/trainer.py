@@ -15,6 +15,12 @@ log still persists per step and covers the window for crash replay);
 `scrub_period` drives `pool.maybe_scrub()`; faults funnel through
 `pool.recover(Fault...)`, which flushes any open window first.
 
+The step path's boundaries are profiler spans on the pool's tracer
+(`Tracer.phase`): `trainer.dispatch` around a step's launch, holding
+`trainer.train_step` and the pool's `pool.commit_dispatch`, and
+`trainer.resolve` around its resolution, holding the pool's
+`pool.resolve` wait on the verdict; each carries `step=<cursor>`.
+
 `overlap_commit` keeps protection off the critical path: step t+1's
 compute is dispatched before step t's commit (and, at an epoch boundary,
 its flush) is awaited — the programs are independent, so the async
@@ -169,6 +175,10 @@ class Trainer(PoolHost):
         values that survive buffer donation are captured (ok / metrics
         are fresh program outputs, never donated operands).
         """
+        with self.pool.tracer.phase("trainer.dispatch", step=self.cursor):
+            return self._dispatch(canary_ok)
+
+    def _dispatch(self, canary_ok) -> dict:
         assert self.prot is not None and not self._frozen
         t0 = time.perf_counter()
         batch = self.stream.device_batch(self.cursor, self._batch_shardings)
@@ -180,7 +190,8 @@ class Trainer(PoolHost):
                 jnp.asarray(mask), NamedSharding(self.mesh, P("data")))
         rng = jax.random.fold_in(jax.random.PRNGKey(self.seed), self.cursor)
         cursor_before = self.cursor
-        new_state, metrics = self._train_step(self.prot.state, batch)
+        with self.pool.tracer.phase("trainer.train_step", step=self.cursor):
+            new_state, metrics = self._train_step(self.prot.state, batch)
         ticket = self.pool.commit_async(new_state, data_cursor=self.cursor,
                                         rng_key=rng, canary_ok=canary_ok,
                                         verify_old=self.verify_old)
@@ -190,6 +201,11 @@ class Trainer(PoolHost):
 
     def _resolve_step(self, pending: dict) -> dict:
         """Await a dispatched step's commit; bookkeeping + scrub cadence."""
+        with self.pool.tracer.phase("trainer.resolve",
+                                    step=pending["cursor_before"]):
+            return self._resolve(pending)
+
+    def _resolve(self, pending: dict) -> dict:
         committed = bool(pending["ticket"].result())
         if committed:
             self._host_step += 1
@@ -218,8 +234,6 @@ class Trainer(PoolHost):
         if not committed:
             reg.counter("trainer_aborted_steps_total").inc()
         reg.gauge("trainer_loss").set(out["loss"])
-        reg.histogram("trainer_step_wall_ms").observe(
-            (time.perf_counter() - pending["t0"]) * 1e3)
         if (self.metrics_dir
                 and self._host_step % self.metrics_every == 0):
             obs.write_metrics(reg, self.metrics_dir, prefix="trainer",

@@ -163,6 +163,45 @@ def test_span_exception_records_error():
     assert validate_events(tr.events) == []
 
 
+def test_tracer_profiler_sink_and_hot_events(tmp_path):
+    opened = []
+
+    class Mark:
+        def __init__(self, name, **ids):
+            self.name, self.ids = name, ids
+
+        def __enter__(self):
+            opened.append(("enter", self.name, self.ids))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name, self.ids))
+
+    bare = Tracer()
+    with bare.phase("trainer.dispatch", step=3):
+        pass                            # no factory: a no-op
+    assert bare.events == [] and opened == []
+    assert bare.emit_hot("commit_dispatch", seq=0) == 0
+    assert bare.emit_hot("commit_dispatch", seq=1) == 1
+    assert bare.events == []            # ids taken, nothing kept
+    assert bare.emit("fault") == 2
+
+    tr = Tracer(str(tmp_path / "t.jsonl"), annotation=Mark)
+    with tr.phase("trainer.dispatch", step=3):
+        pass
+    assert tr.events == []              # a phase writes no event
+    with tr.span("scrub", scope="full"):
+        pass
+    sid = tr.events[0]["id"]
+    assert opened == [("enter", "trainer.dispatch", {"step": 3}),
+                      ("exit", "trainer.dispatch", {"step": 3}),
+                      ("enter", "pool.scrub", {"id": sid}),
+                      ("exit", "pool.scrub", {"id": sid})]
+    hot = tr.emit_hot("commit_dispatch", seq=0)
+    tr.close()
+    assert load_jsonl(str(tmp_path / "t.jsonl"))[-1]["id"] == hot
+    assert validate_events(tr.events) == []
+
+
 # -- live pools: trace linkage on both engines x stack heights ----------------
 
 
