@@ -537,7 +537,7 @@ class Protector:
         return commit
 
     def commit(self, prot, state_new, *, dirty_pages=None, verify_old=False,
-               donate=False, **kw):
+               donate=False, donate_new=False, **kw):
         """Cached-jit commit entry point.
 
         Distinct dirty-page sets (and the verify flag) key distinct
@@ -549,28 +549,73 @@ class Protector:
         cksums, digest, log and state reuse their buffers in place —
         allocation-free steady state); the caller must then drop the old
         `prot` and keep only the returned one.
+
+        `donate_new=True` is the ownership contract for a micro-buffer:
+        the caller hands `state_new` over and never reads it again (it
+        must share no buffer with `prot`).  The committed state then
+        lives in `state_new`'s own buffers — the commit passes it
+        through instead of copying it into the old state's.  An abort
+        still returns the old state, copied into those buffers.
         """
-        return self.commit_program(
+        program = self.commit_program(
             dirty_pages=dirty_pages, verify_old=verify_old,
-            donate=donate)(prot, state_new, **kw)
+            donate=donate, donate_new=donate_new)
+        return program(*self.commit_operands(prot, state_new,
+                                             donate_new=donate_new), **kw)
+
+    @staticmethod
+    def commit_operands(prot, state_new, *, donate_new=False) -> tuple:
+        """The positional operands of `commit_program(donate_new=...)`:
+        `(prot, state_new)`, or for a consuming commit
+        `(state_new, prot without its state, prot.state)`."""
+        if donate_new:
+            return (state_new, dataclasses.replace(prot, state=None),
+                    prot.state)
+        return (prot, state_new)
 
     def commit_program(self, *, dirty_pages=None, verify_old=False,
-                       donate=False):
-        """The cached compiled commit for one (dirty set, verify, donate)
-        key — what `commit` dispatches and what the Pool facade routes
-        through (benchmarks lower it to assert facade == direct bytes)."""
+                       donate=False, donate_new=False):
+        """The cached compiled commit for one (dirty set, verify, donate,
+        donate_new) key — what `commit` dispatches and what the Pool
+        facade routes through (benchmarks lower it to assert facade ==
+        direct bytes).  `commit_operands` gives its arguments.
+
+        JAX pairs each output with the first DONATED input of the same
+        shape and dtype, in flattened-argument order.  The default
+        program takes `(prot, state_new)`: with `donate` the output
+        state lands in `prot.state`'s buffers, so XLA copies `state_new`
+        there.  The `donate_new` program takes `(state_new, prot_meta,
+        state_old)` and donates `state_new` (and, with `donate`,
+        `prot_meta` — `prot` without its state): `state_new` comes
+        first, so the output state aliases it and the pass-through is
+        free.  `state_old` is never donated: every output already has a
+        donated buffer to land in, and XLA would warn of a wasted one.
+        Donating `prot` whole ahead of `state_new`, or anything of
+        state shape before `state_new`, brings the copy back.
+        """
         key = ("commit",
                tuple(int(p) for p in dirty_pages)
                if dirty_pages is not None else None,
-               bool(verify_old), bool(donate))
+               bool(verify_old), bool(donate), bool(donate_new))
         if key not in self._jit_cache:
+            fn = self.make_commit(dirty_pages=dirty_pages,
+                                  verify_old=verify_old)
+            if donate_new:
+                inner = fn
+
+                def commit(state_new, prot_meta, state_old, **kw):
+                    return inner(dataclasses.replace(prot_meta,
+                                                     state=state_old),
+                                 state_new, **kw)
+
+                fn, donated = commit, ((0, 1) if donate else (0,))
+            else:
+                donated = (0,) if donate else ()
             # the canary verdict is host-known before dispatch: static,
             # so the all-clear program folds its abort select-chains away
             # (an abort compiles the cheap no-op variant once)
             self._jit_cache[key] = jax.jit(
-                self.make_commit(dirty_pages=dirty_pages,
-                                 verify_old=verify_old),
-                donate_argnums=(0,) if donate else (),
+                fn, donate_argnums=donated,
                 static_argnames=("canary_ok",))
         return self._jit_cache[key]
 

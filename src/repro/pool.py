@@ -365,6 +365,8 @@ class Pool(EngineHost):
         # hot-path handles: commit() publishes through these cached
         # objects (no registry lookup per transaction)
         self._m_commits = self.metrics.counter("pool_commits_total")
+        self._m_donated_new = self.metrics.counter(
+            "pool_commits_donated_new_total")
         self._m_aborted = self.metrics.counter(
             "pool_commit_aborted_total")
         self._m_commit_ms = self.metrics.histogram(
@@ -562,7 +564,8 @@ class Pool(EngineHost):
 
     def commit(self, state_new: PyTree, *, dirty_pages=None,
                dirty_words=None, data_cursor=0, rng_key=None,
-               canary_ok: bool = True, verify_old: bool = False):
+               canary_ok: bool = True, verify_old: bool = False,
+               donate_new: bool = False):
         """One transactional update; returns the commit verdict (device
         bool — fetch it lazily to keep protection off the critical
         path).
@@ -574,6 +577,12 @@ class Pool(EngineHost):
         engine takes `dirty_pages` (a static page set keying its own
         compiled commit).  Callers pass whichever they know; the pool
         feeds the right one to the engine it built.
+
+        `donate_new=True` hands `state_new` over to the commit (see
+        `Protector.commit`): the caller must not read it afterwards.  The
+        synchronous engine then keeps the committed state in
+        `state_new`'s buffers instead of copying it; the deferred engine
+        ignores the flag.
         """
         assert self.prot is not None, "Pool.commit before init()"
         with self.tracer.phase("pool.commit_dispatch", step=data_cursor):
@@ -589,8 +598,10 @@ class Pool(EngineHost):
                 self._prot, ok = self.protector.commit(
                     self._prot, state_new, dirty_pages=dirty_pages,
                     verify_old=verify_old, donate=self.donate,
-                    data_cursor=data_cursor, rng_key=rng_key,
-                    canary_ok=canary_ok)
+                    donate_new=donate_new, data_cursor=data_cursor,
+                    rng_key=rng_key, canary_ok=canary_ok)
+                if donate_new:
+                    self._m_donated_new.inc()
                 if self._arrival_fn is not None:
                     # synchronous cadence: every commit is its own
                     # window boundary, so the arrival point is right
@@ -634,6 +645,7 @@ class Pool(EngineHost):
     def commit_async(self, state_new: PyTree, *, dirty_pages=None,
                      dirty_words=None, data_cursor=0, rng_key=None,
                      canary_ok=True, verify_old: bool = False,
+                     donate_new: bool = False,
                      extras: Optional[dict] = None) -> CommitTicket:
         """One transactional update as a future: dispatches the commit
         and returns a `CommitTicket` carrying the UNfetched device
@@ -649,7 +661,9 @@ class Pool(EngineHost):
         staged form: the abort select rides inside the program, the
         verdict can't be host-known at dispatch, and abort bookkeeping
         (abort counter, scrub clean-streak) defers to resolution.
-        Routing (`dirty_pages` vs `dirty_words`) matches `commit`.
+        Routing (`dirty_pages` vs `dirty_words`) and `donate_new` match
+        `commit`; the staged form never consumes `state_new` (the old
+        state stays the select's False arm).
         """
         assert self.prot is not None, "Pool.commit_async before init()"
         with self.tracer.phase("pool.commit_dispatch", step=data_cursor):
@@ -683,8 +697,10 @@ class Pool(EngineHost):
                     self._prot, ok = self.protector.commit(
                         self._prot, state_new, dirty_pages=dirty_pages,
                         verify_old=verify_old, donate=self.donate,
-                        data_cursor=data_cursor, rng_key=rng_key,
-                        canary_ok=bool(canary_ok))
+                        donate_new=donate_new, data_cursor=data_cursor,
+                        rng_key=rng_key, canary_ok=bool(canary_ok))
+                    if donate_new:
+                        self._m_donated_new.inc()
                 if self._arrival_fn is not None:
                     new = self._arrival_fn(self._prot, 1, True)
                     if new is not None:

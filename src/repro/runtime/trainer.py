@@ -112,6 +112,17 @@ class Trainer(PoolHost):
 
         self._train_step = jax.jit(api.make_train_step(
             self.model, self.optimizer, train_cfg))
+
+        def train_step(state, batch, into):
+            del into            # a retired state: only its buffers are used
+            # read at trace time: one train step whichever buffers it fills
+            return self._train_step(state, batch)
+
+        # `run` writes each step's micro-buffer into the buffers of the
+        # state the previous commit retired, so two states stay live and
+        # no launch waits for the allocator to free one
+        self._train_step_into = jax.jit(train_step, donate_argnums=(2,),
+                                        keep_unused=True)
         self._batch_shardings = jax.tree.map(
             lambda s: NamedSharding(mesh, s), api.batch_specs(cfg, mesh),
             is_leaf=lambda x: isinstance(x, P))
@@ -168,17 +179,22 @@ class Trainer(PoolHost):
 
     # -- stepping ----------------------------------------------------------------
 
-    def _dispatch_step(self, *, canary_ok: bool = True) -> dict:
+    def _dispatch_step(self, *, canary_ok: bool = True,
+                       into=None) -> dict:
         """Dispatch compute + commit without any host synchronization.
 
         Returns the pending record `_resolve_step` finishes later; only
         values that survive buffer donation are captured (ok / metrics
-        are fresh program outputs, never donated operands).
+        are fresh program outputs, never donated operands).  `into`, a
+        state retired by an earlier commit, is donated to receive the
+        micro-buffer; the record's `retired` is the state this step's
+        commit retired (None if the commit did not consume the
+        micro-buffer) — pop it before the record waits in the ring.
         """
         with self.pool.tracer.phase("trainer.dispatch", step=self.cursor):
-            return self._dispatch(canary_ok)
+            return self._dispatch(canary_ok, into)
 
-    def _dispatch(self, canary_ok) -> dict:
+    def _dispatch(self, canary_ok, into) -> dict:
         assert self.prot is not None and not self._frozen
         t0 = time.perf_counter()
         batch = self.stream.device_batch(self.cursor, self._batch_shardings)
@@ -190,14 +206,27 @@ class Trainer(PoolHost):
                 jnp.asarray(mask), NamedSharding(self.mesh, P("data")))
         rng = jax.random.fold_in(jax.random.PRNGKey(self.seed), self.cursor)
         cursor_before = self.cursor
+        state = self.prot.state
         with self.pool.tracer.phase("trainer.train_step", step=self.cursor):
-            new_state, metrics = self._train_step(self.prot.state, batch)
+            if into is None:
+                new_state, metrics = self._train_step(state, batch)
+            else:
+                new_state, metrics = self._train_step_into(state, batch,
+                                                           into)
+        # new_state is the step's micro-buffer, held by nothing else: the
+        # commit takes it over as the committed state, no copy
         ticket = self.pool.commit_async(new_state, data_cursor=self.cursor,
                                         rng_key=rng, canary_ok=canary_ok,
-                                        verify_old=self.verify_old)
+                                        verify_old=self.verify_old,
+                                        donate_new=True)
+        # a donating pool drops the old state at commit (`Pool.donate`);
+        # once the commit consumed the micro-buffer nothing else reads it
+        retired = state if (self.pool.donate and jax.tree.leaves(
+            new_state)[0].is_deleted()) else None
         self.cursor += 1          # optimistic; rolled back on late abort
         return {"ticket": ticket, "loss": metrics["loss"],
-                "cursor_before": cursor_before, "t0": t0}
+                "cursor_before": cursor_before, "t0": t0,
+                "retired": retired}
 
     def _resolve_step(self, pending: dict) -> dict:
         """Await a dispatched step's commit; bookkeeping + scrub cadence."""
@@ -258,7 +287,9 @@ class Trainer(PoolHost):
         forward compute across the whole ring, not just one step
         behind.  Depth 1 resolves every step inline (the synchronous
         loop); the trailing in-flight steps drain at the end, so a
-        `run` boundary is always fully resolved.
+        `run` boundary is always fully resolved.  From the second step
+        on, each step's micro-buffer is written into the buffers of the
+        state the step before it retired.
         """
         def maybe_checkpoint():
             if (outs and checkpoint_every and self._ckpt_mgr
@@ -268,13 +299,16 @@ class Trainer(PoolHost):
 
         outs = []
         pending: list = []
+        retired = None      # dropped with the run: no third state at rest
         for _ in range(n_steps):
+            rec = self._dispatch_step(into=retired)
+            retired = rec.pop("retired")
             if self.pipeline_depth > 1:
-                pending.append(self._dispatch_step())
+                pending.append(rec)
                 if len(pending) >= self.pipeline_depth:
                     outs.append(self._resolve_step(pending.pop(0)))
             else:
-                outs.append(self.step())
+                outs.append(self._resolve_step(rec))
             maybe_checkpoint()
         while pending:
             # the trailing pipelined steps get the same checkpoint
