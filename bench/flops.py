@@ -5,28 +5,19 @@ what the program happens to execute (recomputation does not count).
 """
 from __future__ import annotations
 
+import harness
+
 
 def matmul_params(cfg: dict) -> int:
-    """Weights that take part in a matmul per token: every layer's
-    projections and MLP, and the output head (the embedding lookup is a
-    gather, not a matmul)."""
-    d, H, K, hd, ff = (cfg["d_model"], cfg["n_heads"], cfg["n_kv"],
-                       cfg["head_dim"], cfg["d_ff"])
-    per_layer = d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * ff
-    return cfg["n_layers"] * per_layer + d * cfg["vocab"]
+    """Weights that take part in a matmul per token (a gather, such as the
+    embedding lookup, is none), from the configuration's layout."""
+    return harness.layout_module(cfg).matmul_params(cfg)
 
 
 def train_flops_per_token(cfg: dict, seq_len: int) -> float:
-    """Forward and backward operations per token of a dense decoder.
-
-    6 per matmul weight (2 forward, 4 backward), plus causal attention:
-    a query at position i scores and mixes i + 1 keys, (S + 1) / 2 on
-    average, at 2 * H * hd operations each for QK^T and for PV, times 3
-    for the backward pass.
-    """
-    attn = 6 * cfg["n_layers"] * cfg["n_heads"] * cfg["head_dim"] \
-        * (seq_len + 1)
-    return 6.0 * matmul_params(cfg) + attn
+    """Forward and backward operations per token at `seq_len`, from the
+    configuration's layout; recomputation does not count."""
+    return harness.layout_module(cfg).train_flops_per_token(cfg, seq_len)
 
 
 def fletcher_bytes(row_words: int, block_words: int) -> int:

@@ -6,13 +6,19 @@ traffic mix.  Each is a data file found by its name: the configuration in
 `configs/<name>.json` (its `file` in BENCHMARK.json), the traffic in
 `traffic/<traffic>.json`, the correctness limits in
 `limits/<workload>.json`.  The traffic's `kind` names the module under
-`kinds/` that runs it, and each per-layer metric is a reader in
-`metrics/<metric>.py`.  Adding a cell, a configuration or a metric adds
-files and entries; no file here changes.
+`kinds/` that runs it, the configuration's `layout` names the module
+under `layouts/` that knows its architecture (its parameter tree, the
+program's model fields, the vocabulary rows held, its operations per
+token, its CPU shrink), its `reference` the plain reference under
+`reference/`, and each per-layer metric is a reader in
+`metrics/<metric>.py`.  Adding a cell, a configuration, an architecture
+or a metric adds files under those directories and entries in
+BENCHMARK.json; no file here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -64,12 +70,22 @@ def load_cell(workload: str, spec_path: pathlib.Path = ROOT / "BENCHMARK.json"
                  if m["moves"] in moved and _reports(m, workload)]
     return Cell(
         workload=w,
-        config=json.loads((ROOT / conf["file"]).read_text()),
+        config=load_config(conf["file"]),
         traffic=json.loads(
             (BENCH / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=json.loads(
             (BENCH / "limits" / f"{workload}.json").read_text()),
         end_to_end=e2e, per_layer=per_layer)
+
+
+def load_config(file: str) -> dict:
+    """A configuration file (relative to the checkout), which has to name
+    its layout."""
+    cfg = json.loads((ROOT / file).read_text())
+    if "layout" not in cfg:
+        raise ValueError(f"{file} names no \"layout\": the module of "
+                         f"bench/layouts/ that lays out its parameters")
+    return cfg
 
 
 def load_module(path: pathlib.Path, name: str):
@@ -82,6 +98,17 @@ def load_module(path: pathlib.Path, name: str):
 
 def kind_module(kind: str):
     return load_module(BENCH / "kinds" / f"{kind}.py", f"kind_{kind}")
+
+
+def layout_module(cfg: dict):
+    """The module of `layouts/` that the configuration names."""
+    return _layout(cfg["layout"])
+
+
+@functools.cache
+def _layout(name: str):
+    return load_module(BENCH / "layouts" / f"{name}.py",
+                       "layout_" + name.replace(".", "_"))
 
 
 def metric_reader(name: str):

@@ -35,12 +35,9 @@ import types
 import numpy as np
 
 import check
+import harness
 import traceview
 import weights
-
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv", "head_dim", "d_ff",
-              "vocab", "qkv_bias", "tie_embeddings", "rope_theta",
-              "param_dtype", "compute_dtype")
 
 
 @dataclasses.dataclass
@@ -69,9 +66,11 @@ class Tokens:
 
 
 def model_config(cfg: dict):
+    """The program's `ModelConfig`: its registry entry with the fields the
+    configuration's layout sets."""
     from repro.configs.registry import get_config
-    return dataclasses.replace(get_config(cfg["program_config"]),
-                               **{k: cfg[k] for k in MODEL_KEYS})
+    overrides = harness.layout_module(cfg).program_overrides(cfg)
+    return dataclasses.replace(get_config(cfg["program_config"]), **overrides)
 
 
 def build(cell, devices, seed: int):
@@ -103,11 +102,17 @@ def build(cell, devices, seed: int):
     return trainer, init
 
 
+def tokens(cell, seed: int) -> Tokens:
+    """The seed's token stream over the rows of the vocabulary that the
+    configuration's layout holds."""
+    tr, cfg = cell.traffic, cell.config
+    return Tokens(seed, harness.layout_module(cfg).vocab(cfg),
+                  tr["global_batch"], tr["seq_len"], tr["token_zipf"])
+
+
 def start(trainer, init, cell, seed: int) -> None:
     """(Re)arm the pool with the seed's state and the stream at step 0."""
-    tr = cell.traffic
-    trainer.stream = Tokens(seed, cell.config["vocab"], tr["global_batch"],
-                            tr["seq_len"], tr["token_zipf"])
+    trainer.stream = tokens(cell, seed)
     trainer.cursor = 0
     trainer.pool.init(init(weights.seed_key(seed)))
 
@@ -130,10 +135,9 @@ def first_steps(trainer, cell, seed: int) -> dict:
 
 
 def reference_batches(cell, seed: int, rows=None) -> np.ndarray:
-    tr = cell.traffic
-    toks = Tokens(seed, cell.config["vocab"], tr["global_batch"],
-                  tr["seq_len"], tr["token_zipf"])
-    out = np.stack([toks.batch_at(c) for c in range(tr["reference_steps"])])
+    toks = tokens(cell, seed)
+    out = np.stack([toks.batch_at(c)
+                    for c in range(cell.traffic["reference_steps"])])
     return out if rows is None else out[:, rows]
 
 
@@ -237,7 +241,6 @@ def run(cell, devices, seed: int, seconds: float, trace: bool,
     """Set-up, window, checks.  Returns (result without checks, checks)."""
     import jax
     from jax.profiler import TraceAnnotation
-    import harness
 
     tr = cell.traffic
     rng = np.random.default_rng([seed % (1 << 63), 0xFA17])

@@ -8,14 +8,23 @@ first half of each batch and takes the mean over it; and, on a zone of
 several chips, a step whose gradients never leave the chip, which reads
 on the first chip as a step over that chip's rows alone.
 """
+import json
+
 import pytest
 
 import harness
 import tiny
 
-CELLS = {"train.glm4-9b.ml": ("stuck", "half_batch"),
-         "train.qwen2-0.5b.mlpc-r2.faults": ("stuck", "half_batch",
-                                              "no_exchange")}
+
+def _faults(workload: dict) -> tuple:
+    """The faults a cell can have: every cell's step can stand still or
+    drop half its batch; only a zone of several chips exchanges."""
+    base = ("stuck", "half_batch")
+    return base + ("no_exchange",) if workload["chips"] > 1 else base
+
+
+CELLS = {w["name"]: _faults(w) for w in json.loads(
+    (harness.ROOT / "BENCHMARK.json").read_text())["workloads"]}
 CASES = [(w, None) for w in CELLS] + [(w, f) for w, fs in CELLS.items()
                                       for f in fs]
 

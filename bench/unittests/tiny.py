@@ -1,11 +1,11 @@
 """A cell of the benchmark cut to a size the CPU runs in seconds.
 
-Same files, same code, same comparison; the configuration's widths, the
-batch and the sequence are shrunk, and the limits are this size's own:
-sound runs on the CPU read up to loss_gap 5.2e-5, grad_gap 0.0084 and
-update_gap 0.011 (seeds 1, 2, 3, 7 and 2**40 + 7, one and four devices);
-the program's fp8 path reads grad_gap 1.0 and update_gap 0.99, half a
-batch reads update_gap 0.2 or more.
+Same files, same code, same comparison; the configuration's widths (its
+layout's `tiny`), the batch and the sequence are shrunk, and the limits
+are this size's own: sound runs on the CPU read up to loss_gap 5.2e-5,
+grad_gap 0.0084 and update_gap 0.011 (seeds 1, 2, 3, 7 and 2**40 + 7,
+one and four devices); the program's fp8 path reads grad_gap 1.0 and
+update_gap 0.99, half a batch reads update_gap 0.2 or more.
 """
 import harness
 
@@ -14,8 +14,7 @@ LIMITS = {"loss_gap": 1.5e-4, "grad_gap": 0.03, "update_gap": 0.03}
 
 def cell(workload: str):
     c = harness.load_cell(workload)
-    c.config.update(n_layers=2, d_model=64, n_heads=4, n_kv=2, head_dim=16,
-                    d_ff=128, vocab=512)
+    c.config.update(harness.layout_module(c.config).tiny(c.config))
     c.traffic.update(global_batch=8, seq_len=32)
     if c.traffic["fault_every"]:
         c.traffic["fault_every"] = 2

@@ -1,46 +1,24 @@
 """Weights and train state made from the seed, owned by the benchmark.
 
 The program under test and the plain reference both start from these
-arrays, and neither makes them: a dense decoder's parameters are laid out
-as the program stores them (one stacked leaf per kind over the layers),
-drawn leaf by leaf from `--seed` in one jitted call on the device.
+arrays, and neither makes them: the parameters are laid out as the
+program stores them (the configuration's module under `layouts/` says
+how), drawn leaf by leaf from `--seed` in one jitted call on the device.
 `delta_norms` draws the same leaves again inside its own program, so the
 change of the parameters after a few steps is measured without a second
 copy of them in memory.
 """
 from __future__ import annotations
 
-import math
+import harness
 
 
 def param_layout(cfg: dict) -> dict:
-    """{path: (shape, std)} of a dense decoder in the program's layout.
-
-    std 0 means ones (norm scales); every other leaf is normal(0, std).
-    """
-    L, d, H, K = cfg["n_layers"], cfg["d_model"], cfg["n_heads"], cfg["n_kv"]
-    hd, ff, V = cfg["head_dim"], cfg["d_ff"], cfg["vocab"]
-    g = "groups/b0_dense/"
-    out = {
-        "embed/tok": ((V, d), 0.02),
-        "final_norm/scale": ((d,), 0.0),
-        g + "ln1/scale": ((L, d), 0.0),
-        g + "ln2/scale": ((L, d), 0.0),
-        g + "attn/wq": ((L, d, H, hd), 1 / math.sqrt(d)),
-        g + "attn/wk": ((L, d, K, hd), 1 / math.sqrt(d)),
-        g + "attn/wv": ((L, d, K, hd), 1 / math.sqrt(d)),
-        g + "attn/wo": ((L, H, hd, d), 1 / math.sqrt(H * hd)),
-        g + "ffn/wi": ((L, d, ff), 1 / math.sqrt(d)),
-        g + "ffn/wg": ((L, d, ff), 1 / math.sqrt(d)),
-        g + "ffn/wo": ((L, ff, d), 1 / math.sqrt(ff)),
-    }
-    if not cfg["tie_embeddings"]:
-        out["embed/unembed"] = ((d, V), 0.02)
-    if cfg["qkv_bias"]:
-        out[g + "attn/bq"] = ((L, H, hd), 0.02)
-        out[g + "attn/bk"] = ((L, K, hd), 0.02)
-        out[g + "attn/bv"] = ((L, K, hd), 0.02)
-    return dict(sorted(out.items()))
+    """{path: (shape, std)} in the program's layout, from the module of
+    `layouts/` that the configuration names.  std 0 means ones (norm
+    scales); every other leaf is normal(0, std).  A leaf's index in this
+    order is its fold-in index."""
+    return harness.layout_module(cfg).param_layout(cfg)
 
 
 def nest(flat: dict) -> dict:
